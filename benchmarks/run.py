@@ -84,7 +84,10 @@ def main(argv=None) -> None:
         commitment_sweep_kernel_stats,
     )
     from benchmarks.paper_benches import ALL_PAPER_BENCHES
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.obs.spans import SpanRecorder
+
+    enable_compile_cache()
 
     benches = ALL_PAPER_BENCHES + ALL_KERNEL_BENCHES
     if args.filter is not None:

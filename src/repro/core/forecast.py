@@ -31,6 +31,13 @@ from repro.core.demand import DAYS_PER_YEAR, HOURS_PER_DAY, HOURS_PER_WEEK
 
 HOURS_PER_YEAR = HOURS_PER_DAY * DAYS_PER_YEAR
 
+#: Every float32 contraction here runs at full float32 precision.  A TPU
+#: rounds matmul inputs to bfloat16 at default precision, which puts a
+#: ~2% error on an exponentiated log-demand forecast and perturbs the
+#: badly conditioned normal equations (cond ~5e4 at a 3-year prefix);
+#: the CPU ignores the setting, so its programs are unchanged.
+_HI = jax.lax.Precision.HIGHEST
+
 
 @dataclasses.dataclass(frozen=True)
 class ForecastConfig:
@@ -84,8 +91,10 @@ class ForecastModel:
 
 def _solve_wls(x, y, w, ridge):
     xw = x * w[:, None]
-    gram = xw.T @ x + ridge * jnp.eye(x.shape[1], dtype=x.dtype)
-    rhs = xw.T @ y
+    gram = jnp.matmul(xw.T, x, precision=_HI) + ridge * jnp.eye(
+        x.shape[1], dtype=x.dtype
+    )
+    rhs = jnp.matmul(xw.T, y, precision=_HI)
     return jnp.linalg.solve(gram, rhs)
 
 
@@ -98,7 +107,7 @@ def _fit(y: jnp.ndarray, cfg: ForecastConfig, t_max: float):
     beta = _solve_wls(x, logy, jnp.ones_like(logy), cfg.ridge)
 
     def irls_step(beta, _):
-        resid = logy - x @ beta
+        resid = logy - jnp.matmul(x, beta, precision=_HI)
         # Under-forecast (actual above prediction) weighted ``asym`` heavier.
         w = jnp.where(resid > 0, cfg.asym_weight, 1.0)
         return _solve_wls(x, logy, w, cfg.ridge), None
@@ -124,7 +133,7 @@ def fit(y: jnp.ndarray, cfg: ForecastConfig = ForecastConfig()) -> ForecastModel
 def predict(model: ForecastModel, t_hours: jnp.ndarray) -> jnp.ndarray:
     """Predict demand at absolute hour indices ``t_hours`` (may be future)."""
     x = design_matrix(t_hours.astype(jnp.float32), model.cfg, model.t_max)
-    return jnp.exp(x @ model.beta)
+    return jnp.exp(jnp.matmul(x, model.beta, precision=_HI))
 
 
 def forecast_horizon(
@@ -218,11 +227,13 @@ def prefix_fit_state(
     d = xh.shape[-1]
     xw = xh.reshape(num_weeks, period_hours, d)
     gram_prefix = jnp.cumsum(
-        jnp.einsum("wtd,wte->wde", xw, xw), axis=0
+        jnp.einsum("wtd,wte->wde", xw, xw, precision=_HI), axis=0
     )
     logy = jnp.log(jnp.maximum(ys, 1e-6))
     lw = logy.reshape(ys.shape[0], num_weeks, period_hours)
-    rhs_prefix = jnp.cumsum(jnp.einsum("wtd,pwt->pwd", xw, lw), axis=1)
+    rhs_prefix = jnp.cumsum(
+        jnp.einsum("wtd,pwt->pwd", xw, lw, precision=_HI), axis=1
+    )
     return PrefixFitState(
         x=x, gram_prefix=gram_prefix, rhs_prefix=rhs_prefix, logy=logy,
         cfg=cfg, t_max=t_max, num_hist_hours=t_hist,
@@ -253,8 +264,8 @@ def solve_prefix_direct(state: PrefixFitState, week) -> jnp.ndarray:
     t = jnp.arange(state.num_hist_hours)
     mask = (t < week * state.period_hours).astype(xh.dtype)
     xm = xh * mask[:, None]
-    g = xm.T @ xh
-    r = jnp.einsum("td,pt->pd", xm, state.logy)
+    g = jnp.matmul(xm.T, xh, precision=_HI)
+    r = jnp.einsum("td,pt->pd", xm, state.logy, precision=_HI)
     return _ridge_solve(g, r, state.cfg.ridge)
 
 
@@ -273,10 +284,12 @@ def irls_refine(
     mask = (t < week * state.period_hours).astype(xh.dtype)
     eye = state.cfg.ridge * jnp.eye(xh.shape[-1], dtype=xh.dtype)
     for _ in range(iters):
-        resid = state.logy - beta @ xh.T                     # (P, T)
+        resid = state.logy - jnp.matmul(beta, xh.T, precision=_HI)  # (P, T)
         w = jnp.where(resid > 0, state.cfg.asym_weight, 1.0) * mask
-        g = jnp.einsum("pt,td,te->pde", w, xh, xh)           # (P, D, D)
-        r = jnp.einsum("pt,td->pd", w * state.logy, xh)
+        g = jnp.einsum(
+            "pt,td,te->pde", w, xh, xh, precision=_HI
+        )                                                    # (P, D, D)
+        r = jnp.einsum("pt,td->pd", w * state.logy, xh, precision=_HI)
         beta = jax.vmap(lambda gi, ri: jnp.linalg.solve(gi + eye, ri))(g, r)
     return beta
 
@@ -327,10 +340,12 @@ def irls_carry_init(
     g_adj = jnp.zeros((num_p, d, d), xh.dtype)
     r_adj = jnp.zeros((num_p, d), xh.dtype)
     for _ in range(max(iters, 0)):
-        resid = state.logy - beta @ xh.T                     # (P, T)
+        resid = state.logy - jnp.matmul(beta, xh.T, precision=_HI)  # (P, T)
         wadj = (state.cfg.asym_weight - 1.0) * (resid > 0) * mask
-        g_adj = jnp.einsum("pt,td,te->pde", wadj, xh, xh)
-        r_adj = jnp.einsum("pt,td->pd", wadj * state.logy, xh)
+        g_adj = jnp.einsum("pt,td,te->pde", wadj, xh, xh, precision=_HI)
+        r_adj = jnp.einsum(
+            "pt,td->pd", wadj * state.logy, xh, precision=_HI
+        )
         beta = solve_prefix_adjusted(state, week, g_adj, r_adj)
     return g_adj, r_adj
 
@@ -358,10 +373,10 @@ def irls_carry_extend(
     lb = jax.lax.dynamic_slice_in_dim(
         state.logy, week * ph, ph, axis=1
     )                                                        # (P, ph)
-    resid = lb - beta @ xb.T
+    resid = lb - jnp.matmul(beta, xb.T, precision=_HI)
     wadj = (state.cfg.asym_weight - 1.0) * (resid > 0)
-    dg = jnp.einsum("pt,td,te->pde", wadj, xb, xb)
-    dr = jnp.einsum("pt,td->pd", wadj * lb, xb)
+    dg = jnp.einsum("pt,td,te->pde", wadj, xb, xb, precision=_HI)
+    dr = jnp.einsum("pt,td->pd", wadj * lb, xb, precision=_HI)
     return gram_adj + dg, rhs_adj + dr
 
 
@@ -371,7 +386,7 @@ def predict_from_beta(
     """(P, num_hours) forecast from prefix-fit betas starting at absolute
     hour ``t_start`` (traced-safe dynamic slice into the shared design)."""
     xf = jax.lax.dynamic_slice_in_dim(state.x, t_start, num_hours, axis=0)
-    return jnp.exp(beta @ xf.T)
+    return jnp.exp(jnp.matmul(beta, xf.T, precision=_HI))
 
 
 def weekly_fractile_levels(
@@ -430,4 +445,4 @@ def fit_batched(ys: jnp.ndarray, cfg: ForecastConfig = ForecastConfig()):
 
 def predict_batched(model: ForecastModel, t_hours: jnp.ndarray) -> jnp.ndarray:
     x = design_matrix(t_hours.astype(jnp.float32), model.cfg, model.t_max)
-    return jnp.exp(model.beta @ x.T)
+    return jnp.exp(jnp.matmul(model.beta, x.T, precision=_HI))

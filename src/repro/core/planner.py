@@ -569,7 +569,8 @@ def _plan_fleet_pools_one_shot(
         # Cloud totals are turnover-invariant; convertible buys the band
         # that is safe at cloud level but above what pools pin themselves
         # (same sizing as the rolling replay's weekly conv pass).
-        total_c = member @ yhat
+        highest = jax.lax.Precision.HIGHEST
+        total_c = jnp.matmul(member, yhat, precision=highest)
         per_h_c = jax.vmap(
             lambda y, q: _prefix_weighted_quantiles(y, w_hours, q)
         )(total_c, qs_c)
@@ -577,7 +578,7 @@ def _plan_fleet_pools_one_shot(
             lambda ph, q: _monotone_stack(ph, q, conv_terms, horizon_weeks)
         )(per_h_c, qs_c)                                          # (C, Kc)
         conv_widths = pf.truncate_convertible_stack(
-            ct, cw, member @ pool_top
+            ct, cw, jnp.matmul(member, pool_top, precision=highest)
         )
         # Need keys on the window's forecast PEAK, mirroring the rolling
         # replay: allocating sunk capacity is free, and a mean-based need

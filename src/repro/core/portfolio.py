@@ -233,15 +233,19 @@ def allocate_convertible(
     alloc = jnp.zeros_like(excess)
     need = excess
     rem = conv_width
+    # Full float32 contractions: a TPU would otherwise round the demand
+    # volumes to bfloat16 inside the cloud aggregation.
+    highest = jax.lax.Precision.HIGHEST
     for _ in range(rounds):
-        cloud_need = membership @ need                       # (C,)
-        give = membership.T @ (
-            rem / jnp.maximum(cloud_need, 1e-9)
+        cloud_need = jnp.matmul(membership, need, precision=highest)  # (C,)
+        give = jnp.matmul(
+            membership.T, rem / jnp.maximum(cloud_need, 1e-9),
+            precision=highest,
         ) * need                                             # (P,)
         give = jnp.minimum(give, need)
         alloc = alloc + give
         need = need - give
-        rem = rem - membership @ give
+        rem = rem - jnp.matmul(membership, give, precision=highest)
     return alloc
 
 
@@ -348,16 +352,22 @@ def _band_assignment(
     the T hours fall below it: per-height cost of covering it with option k
     is alpha_k*(T-j) + beta_k*j, vs od_rate*(T-j) uncovered.  On-demand is
     placed FIRST so cost ties (e.g. a zero-discount option) resolve to no
-    commitment."""
-    j = jnp.arange(t, dtype=jnp.float32)[:, None]
-    lines = jnp.concatenate(
-        [
-            jnp.asarray([[od_rate]], jnp.float32) * (t - j),
-            alphas[None, :] * (t - j) + betas[None, :] * j,
-        ],
-        axis=1,
-    )  # (T, K+1); column 0 = on-demand
-    return jnp.argmin(lines, axis=1)
+    commitment.
+
+    A running minimum over the K lines (a strict ``<`` keeps the earlier
+    line on a tie, as argmin does) never holds the (T, K) cost table:
+    vmapped over rows with per-row lines — the hindsight baseline at
+    fleet scale — that table is (R, T, K), 23 GB at R=16384 rows of
+    three years, past a 16 GiB chip."""
+    j = jnp.arange(t, dtype=jnp.float32)
+    best_cost = jnp.asarray(od_rate, jnp.float32) * (t - j)
+    best = jnp.zeros((t,), jnp.int32)
+    for k in range(alphas.shape[0]):
+        cost = alphas[k] * (t - j) + betas[k] * j
+        better = cost < best_cost
+        best = jnp.where(better, k + 1, best)
+        best_cost = jnp.where(better, cost, best_cost)
+    return best
 
 
 @functools.partial(jax.jit, static_argnames=("od_rate",))
@@ -647,6 +657,7 @@ def optimal_portfolio_grid(
     return plan
 
 
+@functools.partial(jax.jit, static_argnames=("od_rate", "resolution"))
 def handover_fractiles(
     alphas: jnp.ndarray,
     betas: jnp.ndarray,
@@ -658,20 +669,25 @@ def handover_fractiles(
     envelope occupant; 0.0 marks options off the envelope (zero width).
     These are the per-option critical fractiles: the optimal threshold of
     option k on ANY demand curve is its weighted u*_k-quantile — what the
-    horizon planner evaluates on forecast prefixes."""
+    horizon planner evaluates on forecast prefixes.
+
+    Like :func:`_band_assignment`, a running minimum over the K lines
+    keeps every temporary at (resolution,), and compiling the whole loop
+    as one program keeps them out of device memory.  The replay vmaps this
+    over its rows, whose cost lines are not sharded: the (resolution, K)
+    table was (R, 4096, 16), 8.6 GB on one chip at R=32768, and the loop
+    run op by op left that chip 6.75 GB above the other three."""
     u = jnp.linspace(0.0, 1.0, resolution)
-    lines = jnp.concatenate(
-        [
-            (od_rate * (1.0 - u))[:, None],
-            alphas[None, :] * (1.0 - u)[:, None]
-            + betas[None, :] * u[:, None],
-        ],
-        axis=1,
-    )
-    best = jnp.argmin(lines, axis=1) - 1                 # (R,) -1 = od
-    k = alphas.shape[0]
-    mask = best[None, :] == jnp.arange(k)[:, None]
-    hi = jnp.where(mask, u[None, :], -1.0).max(-1)       # (K,)
+    best_cost = od_rate * (1.0 - u)
+    best = jnp.full(u.shape, -1, jnp.int32)              # -1 = od
+    for k in range(alphas.shape[0]):
+        cost = alphas[k] * (1.0 - u) + betas[k] * u
+        better = cost < best_cost
+        best = jnp.where(better, k, best)
+        best_cost = jnp.where(better, cost, best_cost)
+    hi = jnp.stack([
+        jnp.where(best == k, u, -1.0).max() for k in range(alphas.shape[0])
+    ])                                                   # (K,)
     return jnp.where(hi >= 0, hi, 0.0)
 
 

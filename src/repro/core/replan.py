@@ -575,8 +575,8 @@ def replan_fleet_pools(
         # axis through one compiled scan.  Scenario 0 (the realized trace)
         # occupies the first P rows; rows shard over local devices when
         # more than one exists (no-op, bit-identical, on one device).
-        demand = mesh_mod.shard_rows(jnp.asarray(
-            batch.reshape(num_scen * num_pools, t_hist), jnp.float32
+        demand = mesh_mod.shard_rows(np.ascontiguousarray(
+            batch.reshape(num_scen * num_pools, t_hist), np.float32
         ))
         row_clouds = pools.clouds * num_scen
     num_rows = demand.shape[0]
@@ -644,10 +644,11 @@ def replan_fleet_pools(
             """Aggregate per-pool rows (R, ...) onto the per-scenario
             cloud rows (N*C, ...) — block-diagonal membership without a
             widened contraction."""
+            highest = jax.lax.Precision.HIGHEST
             if num_scen == 1:
-                return member @ v
+                return jnp.matmul(member, v, precision=highest)
             vs = v.reshape(num_scen, num_pools, *v.shape[1:])
-            out = jnp.einsum("cp,sp...->sc...", member, vs)
+            out = jnp.einsum("cp,sp...->sc...", member, vs, precision=highest)
             return out.reshape(num_cloud_rows, *v.shape[1:])
 
         conv_rates = jnp.asarray(
@@ -1061,8 +1062,15 @@ def replan_fleet_pools(
         cadence_wk: int, which: str, step_policy: pol.Policy,
         mode: str = "weekly",
     ):
-        active0 = jnp.zeros((num_rows, num_opts), jnp.float32)
-        rolloff0 = jnp.zeros((num_rows, num_opts, sched_len), jnp.float32)
+        # The per-row carries start where the rows live, not whole on the
+        # default device (the roll-off schedule is 656 MB at R=32768).
+        active0 = jnp.zeros(
+            (num_rows, num_opts), jnp.float32, device=demand.sharding
+        )
+        rolloff0 = jnp.zeros(
+            (num_rows, num_opts, sched_len), jnp.float32,
+            device=demand.sharding,
+        )
         if which == "scan":
             step, pstate0 = make_step(
                 cadence_wk, fc.solve_prefix, step_policy, mode

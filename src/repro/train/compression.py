@@ -20,8 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import compat
-
 
 def ef_int8_psum(
     g: jnp.ndarray, err: jnp.ndarray, axis_name: str
@@ -69,7 +67,7 @@ def compressed_pod_sync(
     for g, e, spec in zip(flat_g, flat_e, flat_s):
         spec = spec if spec is not None else P()
 
-        fn = compat.shard_map(
+        fn = jax.shard_map(
             functools.partial(ef_int8_psum, axis_name="pod"),
             mesh=mesh,
             in_specs=(spec, spec),

@@ -13,7 +13,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.models.model import Model
 from repro.train.optimizer import AdamWConfig, adamw_update, init_opt_state
 
@@ -178,7 +177,7 @@ def build_compressed_train_step(
     rep = jax.tree.map(lambda _: P(), param_pspecs, is_leaf=is_p)
 
     def train_step(params, opt_state, err_state, batch):
-        wrapped = compat.shard_map(
+        wrapped = jax.shard_map(
             pod_local,
             mesh=mesh,
             in_specs=(

@@ -1,10 +1,11 @@
 """Unit tests for the dry-run machinery that don't need the 512-device mesh:
 collective parsing, delta configs, rule resolution, sharding sanitization."""
 
+import jax
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro import compat, configs
+from repro import configs
 from repro.launch import hlo_analysis as ha
 from repro.launch.cells import delta_configs, resolve_rules
 from repro.models.config import SHAPES
@@ -75,8 +76,8 @@ class TestDeltaConfigs:
 
 class TestRules:
     def test_resolve_drops_missing_axes(self):
-        mesh = compat.make_mesh((1,), ("data",),
-                                axis_types=compat.auto_axis_types(1))
+        mesh = jax.make_mesh((1,), ("data",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         rules = resolve_rules(dict(RULESETS["train"]), mesh, 256)
         assert rules["batch"] == ("data",)
         assert rules["heads"] is None  # "model" axis doesn't exist
@@ -102,12 +103,12 @@ class TestRules:
 class TestSanitize:
     def _mesh(self):
         # uses whatever devices exist; spec math only needs mesh.shape
-        return compat.make_mesh((1,), ("model",),
-                                axis_types=compat.auto_axis_types(1))
+        return jax.make_mesh((1,), ("model",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
 
     def test_even_dims_untouched(self):
-        mesh = compat.make_mesh((1,), ("model",),
-                                axis_types=compat.auto_axis_types(1))
+        mesh = jax.make_mesh((1,), ("model",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         spec = Spec((32, 64), ("heads", None))
         ps = sanitize_partition_spec(spec, {"heads": "model"}, mesh)
         assert ps == P("model", None)

@@ -163,7 +163,6 @@ SUBPROC_COMPRESS = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro import compat
     from repro.train.compression import ef_int8_psum
 
     mesh = jax.make_mesh((4,), ("pod",))
@@ -172,7 +171,7 @@ SUBPROC_COMPRESS = textwrap.dedent("""
     def step(g, e):
         return ef_int8_psum(g, e, "pod")
 
-    f = jax.jit(compat.shard_map(
+    f = jax.jit(jax.shard_map(
         step, mesh=mesh, in_specs=(P("pod"), P("pod")),
         out_specs=(P("pod"), P("pod")), check_vma=False))
     g = jax.device_put(jnp.asarray(gs), NamedSharding(mesh, P("pod")))

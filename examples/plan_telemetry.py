@@ -16,9 +16,11 @@ the calibration coverage must stay inside the drift gate; this example
 **exits nonzero on reconciliation drift or calibration-gate breach**,
 which is exactly the gate the CI bench-smoke job runs.
 
-Wall time is recorded caller-side with the span profiler
-(`repro.obs.spans`) — the planner core itself never reads a clock
-(analysis rules R2/R7).
+Wall time is recorded with the span profiler (`repro.obs.spans`): the
+example's own spans, and inside `with recording(rec):` the planner's
+host stages (`replan/...`, with the bytes each moves between host and
+device).  The planner core itself never reads a clock (analysis rules
+R2/R7).
 
 The exported JSONL round-trips through the CLI:
 
@@ -32,7 +34,7 @@ import sys
 
 from repro.core import api
 from repro.data import traces
-from repro.obs import SpanRecorder, TelemetryConfig
+from repro.obs import SpanRecorder, TelemetryConfig, recording
 
 
 def main():
@@ -61,7 +63,7 @@ def main():
 
     # All bands on: spot floor, migration-aware forecaster, cloud-level
     # convertible commitments — the richest bill the planner can produce.
-    with rec.span("example/plan", phase="execute"):
+    with rec.span("example/plan", phase="execute"), recording(rec):
         rep = api.plan(api.PlanRequest(
             pools=pools, mode="rolling",
             rolling=api.RollingConfig(cadence_weeks=2, start_weeks=6,

@@ -9,7 +9,9 @@ Two contracts introduced with the observability layer (``repro.obs``):
   then never again, which reads as telemetry but measures nothing.  Real
   per-step observability flows through the cost-attribution ledger
   (``telemetry=`` on the planner) or host-side callbacks — never ambient
-  stdout from inside a trace.
+  stdout from inside a trace.  The same holds for the span profiler's
+  ``span``/``stage``: inside a trace they bracket tracing, once, not the
+  step, so they belong round host calls only.
 
 * **``repro.obs.spans`` is the only wall-clock entry point.**  R2 already
   bans clock reads from the determinism-scoped packages; R7 extends the
@@ -36,6 +38,12 @@ from repro.analysis.rules.r2_determinism import (
 
 #: the one module allowed to read a wall clock (the span profiler).
 CLOCK_ALLOWLIST = ("src/repro/obs/spans.py",)
+
+#: the span profiler's context managers, under every import spelling.
+SPAN_CALLS = frozenset(
+    f"{mod}.{fn}" for mod in ("repro.obs", "repro.obs.spans")
+    for fn in ("span", "stage")
+)
 
 
 def _logging_target(node: ast.Call, imports) -> str | None:
@@ -88,6 +96,14 @@ def run(ctx) -> list[Finding]:
                          "`print()` inside a trace fires once at compile "
                          "time, not per step; route telemetry through the "
                          "ledger/spans instead")
+                    continue
+                name = dotted(callee)
+                full = info.imports.resolve(name) if name else None
+                if full in SPAN_CALLS:
+                    emit(node, full,
+                         f"`{name}()` inside a trace brackets tracing, "
+                         "once, not the step; open spans and stages round "
+                         "host calls only")
                     continue
                 log = _logging_target(node, info.imports)
                 if log is not None:

@@ -33,6 +33,7 @@ from typing import Any, Literal
 from repro.core import forecast as fc
 from repro.core import policy as pol
 from repro.data.scenarios import ScenarioConfig, resolve_scenarios
+from repro.obs import spans as obs_spans
 from repro.obs.config import TelemetryConfig, resolve_telemetry
 
 __all__ = [
@@ -249,9 +250,13 @@ def plan(request: PlanRequest):
         )
     from repro.core import replan
 
-    return replan.replan_fleet_pools(
-        request.pools, request.options, **common,
-        policy=request.policy, scenarios=request.scenarios,
-        telemetry=request.telemetry,
-        **request.rolling_kwargs(),
-    )
+    # The call as a stage of its own: the replay's locals (the host
+    # scenario batch among them) are freed as it returns, after its
+    # ``replan`` stage has closed.
+    with obs_spans.stage("api/plan"):
+        return replan.replan_fleet_pools(
+            request.pools, request.options, **common,
+            policy=request.policy, scenarios=request.scenarios,
+            telemetry=request.telemetry,
+            **request.rolling_kwargs(),
+        )

@@ -15,8 +15,11 @@ R2/R7):
 - ``obs.provenance`` — :class:`~repro.obs.provenance.DecisionLog`, the
   queryable per-week decision record (buys per SKU, roll-offs, binding
   constraints) answering "why does week w hold this stack".
-- ``obs.spans`` — :class:`~repro.obs.spans.SpanRecorder`, the sanctioned
-  caller-side wall clock (compile / execute / host phases).
+- ``obs.spans`` — :func:`~repro.obs.spans.stage`, the planner's host
+  stages as profiler annotations with byte counters, and
+  :class:`~repro.obs.spans.SpanRecorder`, the sanctioned wall clock
+  (compile / execute / host phases), which records those stages inside
+  ``with obs.recording(rec):``.
 - ``obs.kernelstats`` — :class:`~repro.obs.kernelstats.KernelStats` for
   the Pallas commitment-sweep launch shapes.
 
@@ -36,7 +39,7 @@ from repro.obs.config import TelemetryConfig, resolve_telemetry
 from repro.obs.kernelstats import KernelStats, sweep_kernel_stats
 from repro.obs.ledger import CostLedger, LedgerDiff, ledger_from_report
 from repro.obs.provenance import DecisionLog, decision_log_from_arrays
-from repro.obs.spans import Span, SpanRecorder, span
+from repro.obs.spans import Span, SpanRecorder, recording, span, stage
 
 __all__ = [
     "TelemetryConfig",
@@ -53,5 +56,7 @@ __all__ = [
     "decision_log_from_arrays",
     "Span",
     "SpanRecorder",
+    "recording",
     "span",
+    "stage",
 ]

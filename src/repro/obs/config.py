@@ -7,7 +7,11 @@
                         bit-identical to a build without this subsystem
                         (the rolling scan emits no extra outputs at all)
     True                TelemetryConfig() — ledger + kernel stats on
-    TelemetryConfig(...)  pick layers individually, attach a SpanRecorder
+    TelemetryConfig(...)  pick layers individually
+
+Wall-clock timing is not a telemetry layer: the planner's host stages
+are recorded by ``with repro.obs.recording(SpanRecorder()):`` round the
+call, which leaves the compiled program as it is.
 
 Kept separate from ``core.api`` so the obs package has no import cycle
 with the planner: core imports ``obs.config``/``obs.ledger``, while obs
@@ -17,10 +21,6 @@ duck-types the report objects it receives and never imports core.
 from __future__ import annotations
 
 import dataclasses
-import typing
-
-if typing.TYPE_CHECKING:  # pragma: no cover
-    from repro.obs.spans import SpanRecorder
 
 
 #: Forecast fractiles the calibration layer scores each week; the outer
@@ -42,8 +42,6 @@ class TelemetryConfig:
     ``provenance``   emit per-week decision records (buys, roll-offs,
                      binding constraints) and attach a ``DecisionLog``
     ``fractiles``    the forecast fractiles the calibration layer scores
-    ``spans``        optional ``SpanRecorder`` for caller-side wall-clock
-                     phases; never read inside traced code
     """
 
     ledger: bool = True
@@ -51,7 +49,6 @@ class TelemetryConfig:
     calibration: bool = False
     provenance: bool = False
     fractiles: tuple[float, ...] = DEFAULT_FRACTILES
-    spans: "SpanRecorder | None" = None
 
     def __post_init__(self):
         fr = tuple(float(q) for q in self.fractiles)
@@ -71,7 +68,7 @@ class TelemetryConfig:
     def enabled(self) -> bool:
         return (
             self.ledger or self.kernel_stats or self.calibration
-            or self.provenance or self.spans is not None
+            or self.provenance
         )
 
 

@@ -1,40 +1,54 @@
-"""Caller-side wall-clock span profiler (the observability layer's host
+"""Spans: the program's host stages on the profiler's clock, and the
+caller-side wall-clock span recorder (the observability layer's host
 timer).
 
 The planner core is wall-clock-free by contract — analysis rule R2 bans
 clock reads from ``core/``/``capacity/``/``kernels/``/``data/``/``serve/``,
 and rule R7 extends the ban to the whole of ``src/repro`` — so *this
-module* is the single sanctioned place a wall-clock is read.  Everything
-that wants timing (benchmarks, examples, the tournament scoreboard, CI
-artifacts) records **spans** through a :class:`SpanRecorder` owned by the
-caller:
+module* is the single sanctioned place a wall-clock is read.
+
+Program code marks where its host work happens with :func:`stage`:
+
+    with obs_spans.stage("replan/place_rows", h2d_bytes=batch.nbytes):
+        demand = mesh_mod.shard_rows(batch)
+
+A stage opens a ``jax.profiler.TraceAnnotation`` of the same name, so
+under ``jax.profiler`` it lands on the ``/host:CPU`` plane of the device
+trace, on the trace's own clock, with its integer *counts* (bytes moved,
+say) as the event's stats.  When a :class:`SpanRecorder` has been made
+active with :func:`recording`, the stage is also recorded there, counts
+included:
 
     rec = SpanRecorder()
-    with rec.span("tournament/rolling_portfolio", phase="execute"):
-        report = tn.run_tournament(...)
+    with obs_spans.recording(rec):
+        report = api.plan(request)
     print(rec.report())
 
-Spans nest (the recorder keeps a stack, so ``report()`` renders a tree)
-and carry a coarse *phase* tag — ``"compile"`` (tracing + XLA compile),
-``"execute"`` (device compute), ``"host"`` (numpy/report assembly, I/O) —
-the three buckets a JAX program's wall time actually splits into.  The
-recorder never touches traced values: it brackets *host* calls, so R2's
-determinism guarantee (goldens are pure functions of their inputs) is
-untouched — a span changes when the machine does, a golden never.
+With neither a profiler nor an active recorder a stage costs one
+inactive ``TraceMe``: it reads no clock and allocates no :class:`Span`.
+Stages bracket *host* calls only; a stage inside a traced function would
+fire once, at trace time (rule R7 flags it).
 
-Core modules that optionally accept a recorder (``run_tournament(...,
-spans=...)``, ``TelemetryConfig.spans``) take it as an opaque object and
-call only :func:`span` / :meth:`SpanRecorder.span`; the clock read stays
-here.  ``span(None, ...)`` is a zero-cost no-op, so ``spans=None`` paths
-do no timing work at all.
+Callers that own a recorder can still pass it explicitly:
+``span(recorder, name, phase)`` shares the stage's implementation, so
+the tournament's spans appear in traces too, and ``span(None, ...)``
+records nothing.  Spans nest (the recorder keeps a stack, so
+``report()`` renders a tree) and carry a coarse *phase* tag —
+``"compile"`` (tracing + XLA compile), ``"execute"`` (device compute),
+``"host"`` (numpy/report assembly, I/O) — the three buckets a JAX
+program's wall time actually splits into.  A span changes when the
+machine does, a golden never.
 """
 
 from __future__ import annotations
 
 import contextlib
+import contextvars
 import dataclasses
 import json
 import time
+
+import jax
 
 PHASES = ("compile", "execute", "host")
 
@@ -50,6 +64,7 @@ class Span:
     duration_s: float = 0.0
     depth: int = 0
     parent: int = -1
+    counts: dict = dataclasses.field(default_factory=dict)
 
     def to_dict(self) -> dict:
         return {
@@ -59,6 +74,7 @@ class Span:
             "duration_s": self.duration_s,
             "depth": self.depth,
             "parent": self.parent,
+            "counts": dict(self.counts),
         }
 
 
@@ -75,8 +91,9 @@ class SpanRecorder:
         self._stack: list[int] = []
 
     @contextlib.contextmanager
-    def span(self, name: str, phase: str = "host"):
-        """Record ``name`` for the duration of the ``with`` body."""
+    def span(self, name: str, phase: str = "host", **counts: int):
+        """Record ``name``, with its ``counts``, for the duration of the
+        ``with`` body."""
         if phase not in PHASES:
             raise ValueError(f"unknown phase {phase!r}; known: {PHASES}")
         idx = len(self.spans)
@@ -84,6 +101,7 @@ class SpanRecorder:
             name=name, phase=phase, start_s=self._clock(),
             depth=len(self._stack),
             parent=self._stack[-1] if self._stack else -1,
+            counts=counts,
         ))
         self._stack.append(idx)
         try:
@@ -102,14 +120,18 @@ class SpanRecorder:
         return sum(s.duration_s for s in self.spans if s.parent == -1)
 
     def summary(self) -> dict[str, dict]:
-        """name -> {count, total_s, mean_s, phase} over all spans."""
+        """name -> {count, total_s, mean_s, phase, counts} over all spans
+        (``counts`` sums each counter over the spans of that name)."""
         out: dict[str, dict] = {}
         for s in self.spans:
             agg = out.setdefault(
-                s.name, {"count": 0, "total_s": 0.0, "phase": s.phase}
+                s.name,
+                {"count": 0, "total_s": 0.0, "phase": s.phase, "counts": {}},
             )
             agg["count"] += 1
             agg["total_s"] += s.duration_s
+            for k, v in s.counts.items():
+                agg["counts"][k] = agg["counts"].get(k, 0) + v
         for agg in out.values():
             agg["mean_s"] = agg["total_s"] / agg["count"]
         return out
@@ -134,7 +156,10 @@ class SpanRecorder:
         lines = ["span                                   phase     seconds"]
         for s in self.spans:
             label = "  " * s.depth + s.name
-            lines.append(f"{label:38s} {s.phase:9s} {s.duration_s:9.4f}")
+            counts = "".join(f"  {k}={v:,}" for k, v in s.counts.items())
+            lines.append(
+                f"{label:38s} {s.phase:9s} {s.duration_s:9.4f}{counts}"
+            )
         for p, t in self.by_phase().items():
             lines.append(f"{'total ' + p:38s} {'':9s} {t:9.4f}")
         return "\n".join(lines)
@@ -150,13 +175,47 @@ class SpanRecorder:
             )
 
 
+#: The recorder :func:`stage` records into, set by :func:`recording`.
+_ACTIVE: contextvars.ContextVar[SpanRecorder | None] = contextvars.ContextVar(
+    "repro_obs_recorder", default=None
+)
+
+
 @contextlib.contextmanager
-def span(recorder: SpanRecorder | None, name: str, phase: str = "host"):
-    """``recorder.span(...)`` when a recorder is present, a no-op
-    otherwise — the one-liner call sites use so ``spans=None`` costs
-    nothing (and reads no clock at all)."""
-    if recorder is None:
-        yield None
-        return
-    with recorder.span(name, phase=phase) as s:
+def recording(recorder: SpanRecorder):
+    """Make ``recorder`` the one every :func:`stage` inside the ``with``
+    body records into (this thread or task only)."""
+    token = _ACTIVE.set(recorder)
+    try:
+        yield recorder
+    finally:
+        _ACTIVE.reset(token)
+
+
+@contextlib.contextmanager
+def _interval(recorder, name: str, phase: str, counts: dict):
+    """One span: a profiler annotation, and a recorded :class:`Span` when
+    ``recorder`` is not None."""
+    if phase not in PHASES:
+        raise ValueError(f"unknown phase {phase!r}; known: {PHASES}")
+    with jax.profiler.TraceAnnotation(name, **counts):
+        if recorder is None:
+            yield None
+        else:
+            with recorder.span(name, phase=phase, **counts) as s:
+                yield s
+
+
+@contextlib.contextmanager
+def stage(name: str, phase: str = "host", **counts: int):
+    """A host stage of the program: a profiler annotation named ``name``
+    whose stats are ``counts``, recorded by the recorder active when the
+    stage opens, if there is one."""
+    with _interval(_ACTIVE.get(), name, phase, counts) as s:
         yield s
+
+
+def span(recorder: SpanRecorder | None, name: str, phase: str = "host"):
+    """A stage recorded by ``recorder`` (an explicit one, not the active
+    one); ``span(None, ...)`` annotates the trace and records nothing."""
+    return _interval(recorder, name, phase, {})

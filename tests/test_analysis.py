@@ -46,6 +46,7 @@ CASES = {
     "r5_telemetry": "R5",
     "r6": "R6",
     "r7": "R7",
+    "r7_spans": "R7",
 }
 
 

@@ -360,7 +360,7 @@ def bench_fleet_scale(quick: bool = False, seed: int = 0) -> list[Row]:
         if p == oracle_p:
             # Loop-over-scenarios oracle: N unbatched replays over the
             # perturbed paths — the program the batched scan replaces.
-            batch = sc.scenario_batch(pools.demand, cfg)
+            batch = np.asarray(sc.scenario_batch(pools.demand, cfg))
             t0 = time.perf_counter()
             per_scen = []
             for s in range(n_scen):

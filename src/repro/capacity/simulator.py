@@ -416,15 +416,16 @@ def replay_spot_plan(
         demand = np.asarray(pools.demand[:, t0: t0 + s * wk], np.float32)
     else:
         # Re-derive the scenario's demand path: scenario_batch is a pure
-        # function of (realized trace, config) and scenario 0 is the
-        # trace verbatim, so this reproduces the exact rows the scan saw.
+        # function of (realized trace, config), built on the device by the
+        # same module the replay's rows came from, and a one-scenario
+        # slice is the same rows as the whole batch's; np.asarray of it
+        # is the exact rows the scan saw.
         t_hist = (pools.num_hours // wk) * wk
         batch = sc.scenario_batch(
-            pools.demand[:, :t_hist], report.scenario_config
+            pools.demand[:, :t_hist], report.scenario_config,
+            scenario, scenario + 1,
         )
-        demand = np.asarray(
-            batch[scenario][:, t0: t0 + s * wk], np.float32
-        )
+        demand = np.asarray(batch[0, :, t0: t0 + s * wk], np.float32)
     floor = np.repeat(spot_floor.T, wk, axis=1)
     spot_dem = np.maximum(demand - floor, 0.0)            # (P, T)
 

@@ -569,29 +569,37 @@ def replan_fleet_pools(
                 _scen_slice if _scen_slice is not None
                 else (0, scen.n_scenarios)
             )
-            with obs_spans.stage("replan/scenarios"):
+            num_scen = hi - lo
+            # The generator's draws run on the host; the futures are built
+            # on the device from them and the realized rows.
+            with obs_spans.stage(
+                "replan/scenarios", device_rows=num_scen * num_pools
+            ):
                 batch = sc.scenario_batch(
-                    pools.demand[:, :t_hist], scen
-                )[lo:hi]
-            num_scen = batch.shape[0]
+                    pools.demand[:, :t_hist], scen, lo, hi
+                )
             row_clouds = pools.clouds * num_scen
-        # float32 rows placed on the device: the realized trace, and on
-        # scenario replays the flattened batch that replaces it.
-        placed_rows = num_pools + (0 if scen is None else num_scen * num_pools)
-        with obs_spans.stage(
-            "replan/place_rows", h2d_bytes=placed_rows * t_hist * 4
-        ):
-            demand = jnp.asarray(pools.demand[:, :t_hist], jnp.float32)
-            if scen is not None:
+        # Bytes placed on the device: the realized rows, and on scenario
+        # replays the generator's draws beside them.
+        placed = (
+            num_pools * t_hist * 4 if scen is None
+            else sc.placed_bytes(scen, num_pools, t_hist, lo, hi)
+        )
+        with obs_spans.stage("replan/place_rows", h2d_bytes=placed):
+            if scen is None:
+                demand = jnp.asarray(pools.demand[:, :t_hist], jnp.float32)
+            else:
                 # Flatten (N, P) -> N*P rows: every per-pool op in the harness
                 # is row-elementwise or vmapped, so the scenario axis rides the
                 # pool axis through one compiled scan.  Scenario 0 (the
                 # realized trace) occupies the first P rows; rows shard over
                 # local devices when more than one exists (no-op,
-                # bit-identical, on one device).
-                demand = mesh_mod.shard_rows(np.ascontiguousarray(
-                    batch.reshape(num_scen * num_pools, t_hist), np.float32
-                ))
+                # bit-identical, on one device).  The (N, P, T) batch is
+                # dropped here: the scan's rows are the only copy left.
+                demand = mesh_mod.shard_rows(
+                    batch.reshape(num_scen * num_pools, t_hist)
+                )
+                del batch
         num_rows = demand.shape[0]
         # The scenario axis materializes on report arrays only for a true
         # batch (chunked sub-replays always carry it so chunks concatenate).

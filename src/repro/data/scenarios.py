@@ -23,17 +23,28 @@ Every family has a *defining property* the test-suite asserts per seed
 (burst exceedance counts, cyclic lag-168 autocorrelation, declining
 trend sign, ...), and every path is a pure function of
 ``(family, base_seed)`` — reproducibility is part of the contract.
+
+The rolling replay's scenario futures (:func:`scenario_batch`, the
+:data:`PERTURBATIONS` of the realized trace) are built in two steps: the
+host makes each (scenario, pool) row's generator calls and keeps only
+their few draws (:func:`host_draws`), and one compiled device module per
+family turns the realized rows and those draws into the float32 futures,
+on the device the replay scans them on.  Host callers take ``np.asarray``
+of the same rows.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core import demand as dm
 from repro.core.demand import HOURS_PER_WEEK
+from repro.launch import mesh as mesh_mod
 
 FAMILIES: tuple[str, ...] = (
     "steady", "burst", "cyclic", "declining", "unpredictable",
@@ -108,17 +119,35 @@ def _family_config(family: str, pool: int) -> dm.DemandConfig:
     raise ValueError(f"unknown family {family!r}; known: {FAMILIES}")
 
 
+def _num_bursts(t: int) -> int:
+    return max(1, t // (BURST_EVERY_WEEKS * HOURS_PER_WEEK))
+
+
+def _burst_draws(rng: np.random.Generator, t: int):
+    """Start hours and lengths of the bursts of one ``t``-hour series, in
+    the order the generator draws them (length, then start, per burst)."""
+    at, ln = [], []
+    for _ in range(_num_bursts(t)):
+        ln.append(int(rng.integers(*BURST_LEN_HOURS)))
+        at.append(int(rng.integers(0, max(t - ln[-1], 1))))
+    return at, ln
+
+
+def _regime_draws(rng: np.random.Generator, t: int):
+    """Sorted inner edges and per-segment multipliers of one series."""
+    edges = np.sort(rng.integers(1, t, size=REGIME_SEGMENTS - 1))
+    mult = rng.uniform(*REGIME_RANGE, size=REGIME_SEGMENTS)
+    return edges, mult
+
+
 def _transform(
     family: str, y: np.ndarray, rng: np.random.Generator
 ) -> np.ndarray:
     """Family-specific post-transform on one pool's hourly series."""
     t = y.shape[-1]
     if family == "burst":
-        num_bursts = max(1, t // (BURST_EVERY_WEEKS * HOURS_PER_WEEK))
-        for _ in range(num_bursts):
-            ln = int(rng.integers(*BURST_LEN_HOURS))
-            at = int(rng.integers(0, max(t - ln, 1)))
-            y = y.copy()
+        y = y.copy()
+        for at, ln in zip(*_burst_draws(rng, t)):
             y[at:at + ln] *= BURST_FACTOR
         return y
     if family == "cyclic":
@@ -132,10 +161,7 @@ def _transform(
     if family == "unpredictable":
         # Piecewise-constant regime multipliers: level shifts no
         # smooth structural fit anticipates.
-        edges = np.sort(
-            rng.integers(1, t, size=REGIME_SEGMENTS - 1)
-        )
-        mult = rng.uniform(*REGIME_RANGE, size=REGIME_SEGMENTS)
+        edges, mult = _regime_draws(rng, t)
         levels = np.repeat(
             mult, np.diff(np.concatenate([[0], edges, [t]]))
         )
@@ -192,48 +218,166 @@ def resolve_scenarios(
     )
 
 
-def _perturb(family: str, y: np.ndarray, rng: np.random.Generator):
-    """One perturbed copy of one pool's realized hourly series."""
-    t = y.shape[-1]
-    if family == "burst":
-        return _transform("burst", y, rng)
-    if family == "regime":
-        return _transform("unpredictable", y, rng)
-    if family == "growth":
-        g = rng.uniform(*GROWTH_RANGE)
-        ramp = np.exp(g * np.arange(t) / (52.0 * HOURS_PER_WEEK))
-        return y * ramp
-    if family == "scale":
-        return y * rng.lognormal(0.0, SCALE_SIGMA)
+# A perturbation family is three functions of one (s, p) row: ``shapes(t)``
+# lists each draw's trailing shape and host dtype for a ``t``-hour batch;
+# ``draw(rng, t)`` makes the row's generator calls and returns one value
+# per entry of ``shapes``; ``rows(y, *draws)`` is the device's float32 map
+# from the broadcast realized rows (n, P, T) and the stacked draws
+# (n, P, *shape) to the family's futures.
+
+
+def _growth_rows(y, g):
+    t = jnp.arange(y.shape[-1], dtype=jnp.float32)
+    return y * jnp.exp(g[..., None] * t / (52.0 * HOURS_PER_WEEK))
+
+
+def _scale_rows(y, mult):
+    return y * mult[..., None]
+
+
+def _burst_rows(y, at, ln):
+    # One select per burst in draw order, so overlapping bursts compound
+    # through the same float32 products as the in-place host transform.
+    t = jnp.arange(y.shape[-1], dtype=jnp.int32)
+    for k in range(at.shape[-1]):
+        a, n = at[..., k, None], ln[..., k, None]
+        y = jnp.where((t >= a) & (t < a + n), y * BURST_FACTOR, y)
     return y
 
 
-def scenario_batch(demand: np.ndarray, cfg: ScenarioConfig) -> np.ndarray:
-    """(N, P, T) scenario batch derived from the realized ``demand`` (P, T).
+def _regime_rows(y, edges, mult):
+    # Hours from the k-th sorted edge on take multiplier k + 1; the last
+    # edge passed wins, so an empty segment (repeated edge) is skipped.
+    t = jnp.arange(y.shape[-1], dtype=jnp.int32)
+    level = mult[..., :1]
+    for k in range(edges.shape[-1]):
+        level = jnp.where(
+            t >= edges[..., k, None], mult[..., k + 1, None], level
+        )
+    return y * level
+
+
+_PERTURB = {
+    "realized": (lambda t: (), lambda rng, t: (), lambda y: y),
+    "burst": (
+        lambda t: (((_num_bursts(t),), np.int64),) * 2,
+        _burst_draws,
+        _burst_rows,
+    ),
+    "regime": (
+        lambda t: (((REGIME_SEGMENTS - 1,), np.int64),
+                   ((REGIME_SEGMENTS,), np.float64)),
+        _regime_draws,
+        _regime_rows,
+    ),
+    "growth": (
+        lambda t: (((), np.float64),),
+        lambda rng, t: (rng.uniform(*GROWTH_RANGE),),
+        _growth_rows,
+    ),
+    "scale": (
+        lambda t: (((), np.float64),),
+        lambda rng, t: (rng.lognormal(0.0, SCALE_SIGMA),),
+        _scale_rows,
+    ),
+}
+
+
+def _futures(rows, n, first, demand, *draws):
+    """(n, P, T) futures from the realized ``demand`` (P, T) and the
+    stacked draws; when ``first`` the batch opens at scenario 0, which
+    stays the realized trace verbatim."""
+    y = jnp.broadcast_to(demand, (n,) + demand.shape)
+    keep = (jnp.arange(n) == 0) & first
+    return jnp.where(keep[:, None, None], y, rows(y, *draws))
+
+
+#: One compiled device module per family, cached on its shapes.
+_FUTURES = {
+    family: jax.jit(functools.partial(_futures, rows), static_argnums=(0, 1))
+    for family, (_, _, rows) in _PERTURB.items()
+}
+
+
+def _scenario_range(cfg: ScenarioConfig, lo: int, hi: int | None):
+    hi = cfg.n_scenarios if hi is None else hi
+    if not 0 <= lo < hi <= cfg.n_scenarios:
+        raise ValueError(
+            f"scenarios [{lo}, {hi}) outside [0, {cfg.n_scenarios})"
+        )
+    return lo, hi
+
+
+def host_draws(
+    cfg: ScenarioConfig, num_pools: int, num_hours: int,
+    lo: int = 0, hi: int | None = None,
+) -> list[np.ndarray]:
+    """The ``cfg.family`` draws of scenarios ``lo .. hi - 1`` of a
+    ``num_pools`` x ``num_hours`` batch, one (hi - lo, P, *shape) array
+    per draw.  Row (s, p) comes from the generator seeded on
+    ``(1_000_003 x family index, cfg.seed, s, p)``; scenario 0 draws
+    nothing (its entries are zero and unused)."""
+    lo, hi = _scenario_range(cfg, lo, hi)
+    shapes, draw, _ = _PERTURB[cfg.family]
+    out = [np.zeros((hi - lo, num_pools) + shape, dtype)
+           for shape, dtype in shapes(num_hours)]
+    if not out:
+        return out
+    fam_idx = PERTURBATIONS.index(cfg.family)
+    for s in range(max(lo, 1), hi):
+        for p in range(num_pools):
+            rng = np.random.default_rng(
+                (1_000_003 * fam_idx, cfg.seed, s, p)
+            )
+            for arr, v in zip(out, draw(rng, num_hours)):
+                arr[s - lo, p] = v
+    return out
+
+
+def placed_bytes(
+    cfg: ScenarioConfig, num_pools: int, num_hours: int,
+    lo: int = 0, hi: int | None = None,
+) -> int:
+    """Bytes :func:`scenario_batch` places on the device for scenarios
+    ``lo .. hi - 1``: the realized rows and the draws, each in 4-byte
+    words (float32 / int32)."""
+    lo, hi = _scenario_range(cfg, lo, hi)
+    per_row = sum(
+        int(np.prod(shape)) for shape, _ in _PERTURB[cfg.family][0](num_hours)
+    )
+    return 4 * num_pools * (num_hours + (hi - lo) * per_row)
+
+
+def scenario_batch(
+    demand: np.ndarray, cfg: ScenarioConfig,
+    lo: int = 0, hi: int | None = None,
+) -> jax.Array:
+    """(hi - lo, P, T) scenarios ``lo .. hi - 1`` (all N by default) of the
+    batch derived from the realized ``demand`` (P, T), as a device array.
 
     Scenario 0 is the realized trace verbatim; scenarios ``s >= 1`` apply
     the ``cfg.family`` perturbation with a generator seeded on
     ``(family, cfg.seed, s, pool)`` — every batch is a pure function of
     (demand, cfg), reproducibility being part of the contract exactly as
-    for :func:`scenario_path`."""
+    for :func:`scenario_path`, and a slice is the same rows as the whole
+    batch's.  The generator calls run on the host (:func:`host_draws`);
+    the realized rows and those draws are placed on the device, where one
+    compiled module per family builds the rows in float32.  Host callers
+    take ``np.asarray`` of the result: the very rows a replay scans."""
     demand = np.asarray(demand, np.float32)
     if demand.ndim != 2:
         raise ValueError(f"demand must be (P, T), got shape {demand.shape}")
-    if cfg.family == "realized":
-        return np.broadcast_to(
-            demand[None], (cfg.n_scenarios,) + demand.shape
-        ).copy()
-    fam_idx = PERTURBATIONS.index(cfg.family)
-    out = [demand]
-    for s in range(1, cfg.n_scenarios):
-        rows = []
-        for p in range(demand.shape[0]):
-            rng = np.random.default_rng(
-                (1_000_003 * fam_idx, cfg.seed, s, p)
-            )
-            rows.append(_perturb(cfg.family, demand[p], rng))
-        out.append(np.stack(rows).astype(np.float32))
-    return np.stack(out)
+    lo, hi = _scenario_range(cfg, lo, hi)
+    draws = [
+        a.astype(np.float32 if a.dtype.kind == "f" else np.int32)
+        for a in host_draws(cfg, *demand.shape, lo, hi)
+    ]
+    # Draws shard over the scenario axis like the replay's rows, so on
+    # several devices each builds its own scenarios' rows.
+    return _FUTURES[cfg.family](
+        hi - lo, lo == 0, jnp.asarray(demand),
+        *(mesh_mod.shard_rows(a) for a in draws),
+    )
 
 
 def scenario_keys(num_pools: int) -> tuple[dm.PoolKey, ...]:

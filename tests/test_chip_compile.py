@@ -10,7 +10,8 @@ test worker imports this file.  All such compiles live in this one file
 so that they land on one worker.
 
 The hindsight baseline's exact stack solve is compiled at the one-chip
-scale replay's rows too: it is where that replay's memory peaks.
+scale replay's rows too: it is where that replay's memory peaks.  So is
+each scenario family's generator module, at the benchmark's estate.
 
 The persistent compilation cache is turned off around these compiles: an
 entry written for a described chip cannot be read back without one.
@@ -26,12 +27,14 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.experimental.compilation_cache import compilation_cache
 from jax.sharding import SingleDeviceSharding
 
 from repro.capacity import pricing
 from repro.core import portfolio as pf
+from repro.data import scenarios as sc
 from repro.kernels.commitment_sweep import ops
 from repro.launch import compile_cache
 
@@ -95,6 +98,28 @@ def test_hindsight_stack_fits_one_v5e(one_chip):
     ))
     compiled = jax.jit(solve).lower(demand, lines, lines).compile()
     assert 0 < hbm_bytes(compiled) < V5E_HBM_BYTES
+
+
+@pytest.mark.parametrize("family", sc.PERTURBATIONS)
+def test_scenario_futures_build_in_place_on_one_v5e(one_chip, family):
+    """The scenario generator's device module at the benchmark's estate (12
+    pools x 512 futures x 156 weeks): it writes the futures and holds no
+    temporaries beside them."""
+    n, pools, hours = 512, 12, 156 * 168
+    shapes, _, _ = sc._PERTURB[family]
+    args = [jax.ShapeDtypeStruct((pools, hours), jnp.float32,
+                                 sharding=one_chip)] + [
+        jax.ShapeDtypeStruct(
+            (n, pools) + shape,
+            jnp.float32 if np.dtype(dtype).kind == "f" else jnp.int32,
+            sharding=one_chip,
+        )
+        for shape, dtype in shapes(hours)
+    ]
+    mem = sc._FUTURES[family].lower(n, True, *args).compile(
+    ).memory_analysis()
+    assert mem.output_size_in_bytes == n * pools * hours * 4
+    assert mem.temp_size_in_bytes == 0
 
 
 class TestCompileCache:

@@ -493,6 +493,15 @@ class TestStages:
         ]
         return sorted(out, key=lambda e: (e[1], -e[2]))
 
+    def _draw_bytes(self, pools):
+        """Bytes of the generator's draws for the replay's batch, each
+        placed as a 4-byte word."""
+        draws = sc.host_draws(
+            sc.ScenarioConfig(n_scenarios=self.N, family="growth"),
+            pools.num_pools, self.WEEKS * 168,
+        )
+        return 4 * sum(a.size for a in draws)
+
     @staticmethod
     def _pulled_bytes(rep):
         """Bytes of the scan outputs a plain replay pulls, read off its
@@ -542,10 +551,13 @@ class TestStages:
                 assert parent[1] <= start <= end <= parent[2], name
             for a, b in zip(plan[2:6], plan[3:7]):
                 assert a[2] <= b[1], (a[0], b[0])   # siblings in sequence
+            assert by_name["replan/scenarios"][3] == {
+                "device_rows": self.N * pools.num_pools}
             assert by_name["replan/place_rows"][3] == {
-                "h2d_bytes": realized + placed[-1]}
+                "h2d_bytes": realized + self._draw_bytes(pools)}
             assert by_name["replan/pull"][3] == {
                 "d2h_bytes": self._pulled_bytes(rep)}
+        # The scan's rows are built on the device, not placed from the host.
         assert placed[-1] == self.N * realized
         eval_bytes = (self.N * pools.num_pools
                       * (self.WEEKS - self.START) * 168 * 4)
@@ -562,7 +574,9 @@ class TestStages:
         counts = {sp.name: sp.counts for sp in rec.spans if sp.counts}
         realized = pools.num_pools * self.WEEKS * 168 * 4
         assert counts == {
-            "replan/place_rows": {"h2d_bytes": (1 + self.N) * realized},
+            "replan/scenarios": {"device_rows": self.N * pools.num_pools},
+            "replan/place_rows": {
+                "h2d_bytes": realized + self._draw_bytes(pools)},
             "replan/pull": {"d2h_bytes": self._pulled_bytes(rep)},
             "replan/post/baselines": {
                 "d2h_bytes": self.N * pools.num_pools
@@ -571,6 +585,28 @@ class TestStages:
         assert rec.spans[0].parent == -1
         assert all(sp.parent >= 0 for sp in rec.spans[1:])
         assert rec.summary()["replan/pull"]["counts"] == counts["replan/pull"]
+
+    @pytest.mark.parametrize("family", ["burst", "regime"])
+    def test_place_rows_counts_realized_rows_and_draws(self, pools, family):
+        """On a scenario replay the host places the realized rows and the
+        generator's draws; the futures themselves are built on the device
+        (``device_rows`` on ``replan/scenarios``)."""
+        from repro.obs import recording
+
+        cfg = sc.ScenarioConfig(n_scenarios=self.N, family=family)
+        rec = SpanRecorder()
+        with recording(rec):
+            replan.replan_fleet_pools(
+                pools, cadence_weeks=2, start_weeks=self.START,
+                horizon_weeks=4, compare=False, scenarios=cfg,
+            )
+        counts = {sp.name: sp.counts for sp in rec.spans}
+        hours = self.WEEKS * 168
+        draws = sc.host_draws(cfg, pools.num_pools, hours)
+        assert counts["replan/place_rows"] == {"h2d_bytes": 4 * (
+            pools.num_pools * hours + sum(a.size for a in draws))}
+        assert counts["replan/scenarios"] == {
+            "device_rows": self.N * pools.num_pools}
 
     def test_api_plan_brackets_the_replay(self, pools):
         """``api.plan`` wraps the replay in ``api/plan``, so what runs as

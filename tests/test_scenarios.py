@@ -228,3 +228,116 @@ class TestScenarioBatch:
             sc.scenario_batch(
                 np.zeros(10, np.float32), sc.ScenarioConfig(n_scenarios=2)
             )
+
+
+def _loop_draws(family, rng, t):
+    """The generator calls of one (s, p) row as the per-row host loop made
+    them, value by value, in call order."""
+    if family == "burst":
+        out = []
+        for _ in range(max(1, t // (sc.BURST_EVERY_WEEKS * WK))):
+            ln = int(rng.integers(*sc.BURST_LEN_HOURS))
+            at = int(rng.integers(0, max(t - ln, 1)))
+            out += [at, ln]
+        return out
+    if family == "regime":
+        edges = np.sort(rng.integers(1, t, size=sc.REGIME_SEGMENTS - 1))
+        mult = rng.uniform(*sc.REGIME_RANGE, size=sc.REGIME_SEGMENTS)
+        return [*edges, *mult]
+    if family == "growth":
+        return [rng.uniform(*sc.GROWTH_RANGE)]
+    if family == "scale":
+        return [rng.lognormal(0.0, sc.SCALE_SIGMA)]
+    return []
+
+
+def _oracle_row(family, y, vals):
+    """Float64 perturbation of one realized row from its loop draws."""
+    y = np.asarray(y, np.float64)
+    t = np.arange(y.shape[-1])
+    if family == "burst":
+        out = y.copy()
+        for at, ln in zip(vals[0::2], vals[1::2]):
+            out[at:at + ln] *= sc.BURST_FACTOR
+        return out
+    if family == "regime":
+        k = sc.REGIME_SEGMENTS - 1
+        edges, mult = np.asarray(vals[:k], int), np.asarray(vals[k:])
+        return y * mult[np.searchsorted(edges, t, side="right")]
+    if family == "growth":
+        return y * np.exp(vals[0] * t / (52.0 * WK))
+    if family == "scale":
+        return y * vals[0]
+    return y
+
+
+class TestDeviceBatch:
+    """``scenario_batch`` as host draws plus one device module: the draws
+    are the per-(s, p) generator's calls exactly, the device rows are the
+    float64 perturbation to float32 precision, and a slice of the batch is
+    the same rows as the whole."""
+
+    N, P, T, SEED = 4, 3, 5 * WK, 11
+
+    def _demand(self):
+        rng = np.random.default_rng(3)
+        return rng.gamma(2.0, 40.0, (self.P, self.T)).astype(np.float32)
+
+    def _cfg(self, family):
+        return sc.ScenarioConfig(n_scenarios=self.N, family=family,
+                                 seed=self.SEED)
+
+    def _loop(self, family):
+        """{(s, p): draws} of the per-row loop, scenarios 1 .. N - 1."""
+        fam_idx = sc.PERTURBATIONS.index(family)
+        return {
+            (s, p): _loop_draws(family, np.random.default_rng(
+                (1_000_003 * fam_idx, self.SEED, s, p)), self.T)
+            for s in range(1, self.N) for p in range(self.P)
+        }
+
+    @pytest.mark.parametrize("family", sc.PERTURBATIONS)
+    def test_host_draws_are_the_loop_draws(self, family):
+        draws = sc.host_draws(self._cfg(family), self.P, self.T)
+        for (s, p), vals in self._loop(family).items():
+            got = [v for a in draws for v in np.ravel(a[s, p])]
+            if family == "burst":   # (at..., ln...) -> (at, ln) pairs
+                nb = len(got) // 2
+                got = [v for pair in zip(got[:nb], got[nb:]) for v in pair]
+            assert got == vals, (s, p)
+
+    @pytest.mark.parametrize("family", sc.PERTURBATIONS)
+    def test_device_rows_match_float64_oracle(self, family):
+        d = self._demand()
+        batch = np.asarray(sc.scenario_batch(d, self._cfg(family)))
+        assert batch.dtype == np.float32
+        for (s, p), vals in self._loop(family).items():
+            np.testing.assert_allclose(
+                batch[s, p], _oracle_row(family, d[p], vals), rtol=2e-6,
+                err_msg=f"scenario {s} pool {p}",
+            )
+
+    @pytest.mark.parametrize("family", sc.PERTURBATIONS)
+    @pytest.mark.parametrize("lo,hi", [(0, 2), (1, 3), (3, 4)])
+    def test_slice_is_the_whole_batchs_rows(self, family, lo, hi):
+        d = self._demand()
+        cfg = self._cfg(family)
+        part = np.asarray(sc.scenario_batch(d, cfg, lo, hi))
+        np.testing.assert_array_equal(
+            part, np.asarray(sc.scenario_batch(d, cfg))[lo:hi]
+        )
+        if lo == 0:
+            np.testing.assert_array_equal(part[0], d)
+
+    @pytest.mark.parametrize("family", sc.PERTURBATIONS)
+    def test_placed_bytes_are_realized_rows_and_draws(self, family):
+        cfg = self._cfg(family)
+        draws = sc.host_draws(cfg, self.P, self.T, 1, 3)
+        assert sc.placed_bytes(cfg, self.P, self.T, 1, 3) == (
+            self._demand().nbytes + 4 * sum(a.size for a in draws)
+        )
+
+    @pytest.mark.parametrize("lo,hi", [(-1, 2), (2, 2), (0, 5)])
+    def test_bad_slice(self, lo, hi):
+        with pytest.raises(ValueError, match="outside"):
+            sc.scenario_batch(self._demand(), self._cfg("growth"), lo, hi)

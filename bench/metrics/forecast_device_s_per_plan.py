@@ -1,0 +1,12 @@
+"""Device seconds per plan in the forecasting policy's weekly refit and
+forecast: the ops under ``jax.named_scope("forecast")`` in the scan body
+(``core/policy.py``), on the trace (``lib/scopes``).  Both scans of a
+plan count: the rolling replay's and, with ``compare`` on, the one-shot
+baseline's, which runs the same scan body.  Nothing to read where the
+program's ops carry no such scope."""
+
+from lib import scopes
+
+
+def read(record):
+    return scopes.seconds_per_plan(record, __file__, "forecast")

@@ -23,9 +23,11 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
+import jax.scipy.linalg as jsl
 
 from repro.core.demand import DAYS_PER_YEAR, HOURS_PER_DAY, HOURS_PER_WEEK
 
@@ -34,8 +36,11 @@ HOURS_PER_YEAR = HOURS_PER_DAY * DAYS_PER_YEAR
 #: Every float32 contraction here runs at full float32 precision.  A TPU
 #: rounds matmul inputs to bfloat16 at default precision, which puts a
 #: ~2% error on an exponentiated log-demand forecast and perturbs the
-#: badly conditioned normal equations (cond ~5e4 at a 3-year prefix);
-#: the CPU ignores the setting, so its programs are unchanged.
+#: badly conditioned normal equations: on the paper's 156-week estate
+#: with the yearly terms off (D = 31), cond(G + ridge I) is 1.3e6 at an
+#: 8-week prefix, 2.0e7 at 100 weeks, 2.5e7 at 141 and 5.6e4 at 156
+#: (8.6e3-3.6e4 once Jacobi-scaled, see :func:`_spd_solve`); the CPU
+#: ignores the setting, so its programs are unchanged.
 _HI = jax.lax.Precision.HIGHEST
 
 
@@ -52,20 +57,28 @@ class ForecastConfig:
     holiday_len_days: int = 9
 
 
-def _fourier(t: jnp.ndarray, period: float, order: int) -> jnp.ndarray:
-    """(T, 2*order) Fourier design block."""
-    k = jnp.arange(1, order + 1, dtype=jnp.float32)
-    ang = 2.0 * jnp.pi * t[:, None] * k[None, :] / period
+def _fourier(t: jnp.ndarray, period: int, order: int) -> jnp.ndarray:
+    """(T, 2*order) Fourier design block of integer hours ``t``.  The phase
+    t k is reduced modulo the period in int32 (exact: t k stays far below
+    2^31), centred on zero, and only then scaled to an angle in float32,
+    so every entry is within a few float32 ulps of the exact design.  A
+    float32 angle of 2 pi t k / period itself reaches 5.8e4 rad over a
+    three-year history and carries an error of up to 2.7e-3."""
+    k = jnp.arange(1, order + 1, dtype=jnp.int32)
+    m = (t[:, None] * k[None, :]) % period
+    m = jnp.where(2 * m >= period, m - period, m)
+    ang = m.astype(jnp.float32) * (2.0 * math.pi / period)
     return jnp.concatenate([jnp.sin(ang), jnp.cos(ang)], axis=-1)
 
 
 def design_matrix(
     t_hours: jnp.ndarray, cfg: ForecastConfig, t_max: float
 ) -> jnp.ndarray:
-    """Feature matrix X (T, D).  ``t_max`` fixes changepoint locations so the
-    same basis extends consistently into the future."""
-    t = t_hours.astype(jnp.float32)
-    ts = t / t_max  # normalized time for trend columns
+    """Feature matrix X (T, D) at whole hours ``t_hours``.  ``t_max`` fixes
+    changepoint locations so the same basis extends consistently into the
+    future."""
+    t = jnp.asarray(t_hours).astype(jnp.int32)
+    ts = t.astype(jnp.float32) / t_max  # normalized time for trend columns
     cols = [jnp.ones_like(ts)[:, None], ts[:, None]]
     if cfg.num_changepoints:
         cps = jnp.linspace(0.1, 0.9, cfg.num_changepoints)
@@ -73,7 +86,7 @@ def design_matrix(
     cols.append(_fourier(t, HOURS_PER_DAY, cfg.daily_order))
     cols.append(_fourier(t, HOURS_PER_WEEK, cfg.weekly_order))
     cols.append(_fourier(t, HOURS_PER_YEAR, cfg.yearly_order))
-    day_of_year = jnp.mod(t // HOURS_PER_DAY, DAYS_PER_YEAR)
+    day_of_year = (t // HOURS_PER_DAY) % DAYS_PER_YEAR
     holiday = (
         (day_of_year >= cfg.holiday_start_day)
         & (day_of_year < cfg.holiday_start_day + cfg.holiday_len_days)
@@ -89,13 +102,27 @@ class ForecastModel:
     cfg: ForecastConfig
 
 
+def _spd_solve(g: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """Solve g x = b for a symmetric positive definite g (D, D) and b
+    (D, ...).  The system is Jacobi-scaled first, (S g S) z = S b with
+    S = diag(g)^(-1/2) and x = S z, which takes the ridge normal
+    equations from cond ~2.5e7 to ~3.6e4 (see ``_HI``) without moving the
+    solution, and then solved by Cholesky at HIGHEST precision: one
+    factorization shared by every column of b."""
+    s = jax.lax.rsqrt(jnp.diagonal(g))
+    sb = s.reshape(s.shape + (1,) * (b.ndim - 1))
+    with jax.default_matmul_precision("highest"):
+        c = jsl.cho_factor(g * s[:, None] * s[None, :])
+        return sb * jsl.cho_solve(c, sb * b)
+
+
 def _solve_wls(x, y, w, ridge):
     xw = x * w[:, None]
     gram = jnp.matmul(xw.T, x, precision=_HI) + ridge * jnp.eye(
         x.shape[1], dtype=x.dtype
     )
     rhs = jnp.matmul(xw.T, y, precision=_HI)
-    return jnp.linalg.solve(gram, rhs)
+    return _spd_solve(gram, rhs)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
@@ -157,9 +184,10 @@ def weighted_mape(
 
 def _ridge_solve(gram: jnp.ndarray, rhs: jnp.ndarray, ridge: float):
     """Solve (gram + ridge I) beta = rhs for one shared gram and a batch of
-    right-hand sides rhs (P, D) -> (P, D)."""
+    right-hand sides rhs (P, D) -> (P, D): one factorization per call,
+    the rows one batch of right-hand sides."""
     g = gram + ridge * jnp.eye(gram.shape[-1], dtype=gram.dtype)
-    return jnp.linalg.solve(g, rhs.T).T
+    return _spd_solve(g, rhs.T).T
 
 
 @dataclasses.dataclass(frozen=True)
@@ -199,6 +227,81 @@ class PrefixFitState:
         return self.gram_prefix.shape[0]
 
 
+def _prefix_config(
+    cfg: ForecastConfig, t_hist: int, min_prefix_hours: int | None
+) -> ForecastConfig:
+    """``cfg`` with the yearly terms off where the shortest prefix a refit
+    sees (``min_prefix_hours``, else the whole ``t_hist``) is under 1.2
+    years."""
+    guard_hours = t_hist if min_prefix_hours is None else min_prefix_hours
+    if guard_hours < 1.2 * HOURS_PER_YEAR and cfg.yearly_order:
+        return dataclasses.replace(cfg, yearly_order=0)
+    return cfg
+
+
+def num_features(cfg: ForecastConfig) -> int:
+    """Columns D of :func:`design_matrix` under ``cfg``."""
+    fourier = cfg.daily_order + cfg.weekly_order + cfg.yearly_order
+    return 3 + cfg.num_changepoints + 2 * fourier
+
+
+def prefix_state_bytes(
+    num_rows: int,
+    num_hours: int,
+    cfg: ForecastConfig = ForecastConfig(),
+    *,
+    horizon_hours: int,
+    period_hours: int = HOURS_PER_WEEK,
+    min_prefix_hours: int | None = None,
+) -> int:
+    """Bytes of the design, the prefix Gram and the right-hand sides that
+    :func:`prefix_fit_state` builds for ``num_rows`` rows of ``num_hours``
+    hours (float32), from the shapes alone."""
+    num_weeks = num_hours // period_hours
+    t_hist = num_weeks * period_hours
+    d = num_features(_prefix_config(cfg, t_hist, min_prefix_hours))
+    return 4 * d * (t_hist + horizon_hours + num_weeks * (d + num_rows))
+
+
+def _pairwise_sum(a: jnp.ndarray, axis: int) -> jnp.ndarray:
+    """Sum over ``axis`` by halving it: each entry takes ~log2(n) float32
+    roundings, where a running sum takes ~n."""
+    a = jnp.moveaxis(a, axis, 0)
+    while a.shape[0] > 1:
+        if a.shape[0] % 2:
+            a = jnp.concatenate([a, jnp.zeros_like(a[:1])])
+        a = a.reshape(2, a.shape[0] // 2, *a.shape[1:])
+        a = a[0] + a[1]
+    return a[0]
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("cfg", "num_weeks", "period_hours", "horizon_hours",
+                     "t_max"),
+)
+def _prefix_arrays(ys, cfg, num_weeks, period_hours, horizon_hours, t_max):
+    """The design over history and horizon, the prefix Grams, the prefix
+    right-hand sides and the log targets of :func:`prefix_fit_state`, in
+    one compiled call."""
+    t_hist = num_weeks * period_hours
+    ys = ys[..., :t_hist]
+    x = design_matrix(jnp.arange(t_hist + horizon_hours), cfg, t_max)
+    xw = x[:t_hist].reshape(num_weeks, period_hours, x.shape[-1])
+    # The pool-shared weekly Gram blocks sum their hours pairwise, one week
+    # at a time: a float32 dot over the 168 hours put 2e-4 of a pool's mean
+    # on the forecast in the week after a changepoint.
+    gram_prefix = jnp.cumsum(jax.lax.map(
+        lambda xb: _pairwise_sum(xb[:, :, None] * xb[:, None, :], axis=0), xw
+    ), axis=0)
+    logy = jnp.log(jnp.maximum(ys, 1e-6))
+    lw = logy.reshape(ys.shape[0], num_weeks, period_hours)
+    rhs_prefix = jnp.cumsum(
+        jnp.einsum("wtd,pwt->pwd", xw, lw, precision=_HI), axis=1
+    )
+    return x, gram_prefix, rhs_prefix, logy
+
+
 def prefix_fit_state(
     ys: jnp.ndarray,
     cfg: ForecastConfig = ForecastConfig(),
@@ -216,23 +319,10 @@ def prefix_fit_state(
     ys = jnp.asarray(ys, jnp.float32)
     num_weeks = ys.shape[-1] // period_hours
     t_hist = num_weeks * period_hours
-    ys = ys[..., :t_hist]
-    guard_hours = t_hist if min_prefix_hours is None else min_prefix_hours
-    if guard_hours < 1.2 * HOURS_PER_YEAR and cfg.yearly_order:
-        cfg = dataclasses.replace(cfg, yearly_order=0)
+    cfg = _prefix_config(cfg, t_hist, min_prefix_hours)
     t_max = float(max(t_hist - 1, 1))
-    t_all = jnp.arange(t_hist + horizon_hours, dtype=jnp.float32)
-    x = design_matrix(t_all, cfg, t_max)
-    xh = x[:t_hist]
-    d = xh.shape[-1]
-    xw = xh.reshape(num_weeks, period_hours, d)
-    gram_prefix = jnp.cumsum(
-        jnp.einsum("wtd,wte->wde", xw, xw, precision=_HI), axis=0
-    )
-    logy = jnp.log(jnp.maximum(ys, 1e-6))
-    lw = logy.reshape(ys.shape[0], num_weeks, period_hours)
-    rhs_prefix = jnp.cumsum(
-        jnp.einsum("wtd,pwt->pwd", xw, lw, precision=_HI), axis=1
+    x, gram_prefix, rhs_prefix, logy = _prefix_arrays(
+        ys, cfg, num_weeks, period_hours, horizon_hours, t_max
     )
     return PrefixFitState(
         x=x, gram_prefix=gram_prefix, rhs_prefix=rhs_prefix, logy=logy,
@@ -290,7 +380,7 @@ def irls_refine(
             "pt,td,te->pde", w, xh, xh, precision=_HI
         )                                                    # (P, D, D)
         r = jnp.einsum("pt,td->pd", w * state.logy, xh, precision=_HI)
-        beta = jax.vmap(lambda gi, ri: jnp.linalg.solve(gi + eye, ri))(g, r)
+        beta = jax.vmap(lambda gi, ri: _spd_solve(gi + eye, ri))(g, r)
     return beta
 
 
@@ -317,7 +407,7 @@ def solve_prefix_adjusted(
     )
     eye = state.cfg.ridge * jnp.eye(g.shape[-1], dtype=g.dtype)
     return jax.vmap(
-        lambda ga, ri: jnp.linalg.solve(g + ga + eye, ri)
+        lambda ga, ri: _spd_solve(g + ga + eye, ri)
     )(gram_adj, r + rhs_adj)
 
 

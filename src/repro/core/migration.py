@@ -35,6 +35,7 @@ drift."""
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -194,6 +195,23 @@ def share_prefix_state(
     whole periods, matching ``forecast.prefix_fit_state``).  The prior
     moments, if any, ride inside every prefix (the announced-launch prior
     binds hardest exactly when the prefix holds no adoption signal)."""
+    return SharePrefixState(
+        cum=_share_cum(
+            jnp.asarray(demand, jnp.float32), edges, t_max=float(t_max),
+            period_hours=period_hours, prior_weight=float(prior_weight),
+        ),
+        t_max=jnp.float32(t_max),
+    )
+
+
+@functools.partial(
+    jax.jit, static_argnames=("t_max", "period_hours", "prior_weight")
+)
+def _share_cum(demand, edges, t_max, period_hours, prior_weight):
+    """(G, W, 5) cumulative weekly moments of :func:`share_prefix_state`,
+    in one compiled call: no (G, T) intermediate outlives it, so the
+    device memory it takes does not hang on how far the host has run
+    ahead of the device."""
     z, w = share_observations(demand, edges)
     g = z.shape[0]
     num_weeks = z.shape[-1] // period_hours
@@ -207,10 +225,13 @@ def share_prefix_state(
     cum = jnp.cumsum(weekly, axis=1)
     if prior_weight > 0:
         cum = cum + _prior_moments(edges, t_max, prior_weight)[:, None, :]
-    return SharePrefixState(
-        cum=cum,
-        t_max=jnp.float32(t_max),
-    )
+    return cum
+
+
+def share_state_bytes(num_edges: int, num_weeks: int) -> int:
+    """Bytes of the :class:`SharePrefixState` of ``num_edges`` edges over
+    ``num_weeks`` whole periods: the float32 moments and ``t_max``."""
+    return 4 * (num_edges * num_weeks * 5 + 1)
 
 
 def solve_share_prefix(

@@ -236,21 +236,27 @@ class RollingPortfolioPolicy(Policy):
                 inner, (lo, hi) = pstate
             else:
                 inner = pstate
-            if carry_irls:
-                g_adj, r_adj = inner
-                beta = fc.solve_prefix_adjusted(ctx.state, w, g_adj, r_adj)
-                inner = fc.irls_carry_extend(
-                    ctx.state, beta, g_adj, r_adj, w
+            # Device scopes: the refit and forecast, then the stack and
+            # spot solve (the trace's op names carry them).
+            with jax.named_scope("forecast"):
+                if carry_irls:
+                    g_adj, r_adj = inner
+                    beta = fc.solve_prefix_adjusted(
+                        ctx.state, w, g_adj, r_adj
+                    )
+                    inner = fc.irls_carry_extend(
+                        ctx.state, beta, g_adj, r_adj, w
+                    )
+                else:
+                    beta = ctx.solve_fn(ctx.state, w)
+                    beta = fc.irls_refine(ctx.state, beta, w, ctx.irls_iters)
+                yhat = fc.predict_from_beta(
+                    ctx.state, beta, w * HOURS_PER_WEEK, ctx.horizon_hours
                 )
-            else:
-                beta = ctx.solve_fn(ctx.state, w)
-                beta = fc.irls_refine(ctx.state, beta, w, ctx.irls_iters)
-            yhat = fc.predict_from_beta(
-                ctx.state, beta, w * HOURS_PER_WEEK, ctx.horizon_hours
-            )
-            if ctx.compose_forecast is not None:
-                yhat = ctx.compose_forecast(yhat, w)
-            targets, floor = ctx.targets_for(yhat)
+                if ctx.compose_forecast is not None:
+                    yhat = ctx.compose_forecast(yhat, w)
+            with jax.named_scope("solve"):
+                targets, floor = ctx.targets_for(yhat)
             if not breach:
                 return inner, Decision(
                     targets, floor, yhat, self._is_decision(ctx, w)

@@ -720,17 +720,27 @@ def replan_fleet_pools(
                         "use a forecasting policy"
                     )
 
-            state = fc.prefix_fit_state(
-                fit_demand, cfg, horizon_hours=horizon_hours,
+            # The forecaster's state: the design, the prefix Gram, the
+            # right-hand sides and, with migration, the share moments.
+            prefix_bytes = fc.prefix_state_bytes(
+                num_rows, t_hist, cfg, horizon_hours=horizon_hours,
                 min_prefix_hours=start_weeks * HOURS_PER_WEEK,
-            )
-            share_state = (
-                mg.share_prefix_state(
-                    demand, edges, t_max=state.t_max,
-                    prior_weight=mig_cfg.share_prior_weight,
+            ) + (mg.share_state_bytes(edges.num_edges, total_weeks)
+                 if use_mig else 0)
+            with obs_spans.stage(
+                "replan/prepare/forecast", prefix_bytes=prefix_bytes
+            ):
+                state = fc.prefix_fit_state(
+                    fit_demand, cfg, horizon_hours=horizon_hours,
+                    min_prefix_hours=start_weeks * HOURS_PER_WEEK,
                 )
-                if use_mig else None
-            )
+                share_state = (
+                    mg.share_prefix_state(
+                        demand, edges, t_max=state.t_max,
+                        prior_weight=mig_cfg.share_prior_weight,
+                    )
+                    if use_mig else None
+                )
             demand_wk = demand.reshape(num_rows, total_weeks, HOURS_PER_WEEK)
 
         def grid_prefix_levels(yhat, al, be, num_rows, num_k):
@@ -954,7 +964,8 @@ def replan_fleet_pools(
                     # convertible bought there would bill the same demand
                     # twice.
                     pool_top = jnp.maximum(widths.sum(-1), active.sum(-1))
-                    widths_c = conv_targets_for(yhat, pool_top)
+                    with jax.named_scope("solve"):
+                        widths_c = conv_targets_for(yhat, pool_top)
                     inc_c = jnp.maximum(widths_c - active_c, 0.0)
                     inc_c = jnp.where(
                         dec_c & (inc_c > ld.PURCHASE_EPS), inc_c, 0.0

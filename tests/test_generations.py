@@ -299,6 +299,23 @@ class TestDriverDecomposition:
             np.asarray(b_pre), np.asarray(b_full), rtol=2e-4, atol=2e-4
         )
 
+    def test_share_prefix_state_is_one_compiled_call(self, fleet):
+        """The share moments are built in one jitted call: eager ops on
+        (G, T) arrays would each hold an output until the device ran them,
+        so the stage's peak memory would hang on host timing."""
+        _, pools = fleet
+        edges = gn.migration_edges(pools.keys, PLANT)
+        d = jnp.asarray(pools.demand)
+        t_max = float(pools.num_hours - 1)
+        jaxpr = jax.make_jaxpr(
+            lambda x: mg.share_prefix_state(
+                x, edges, t_max=t_max, prior_weight=100.0
+            ).cum
+        )(d)
+        calls = [e for e in jaxpr.eqns if e.primitive.name in ("pjit", "jit")]
+        assert len(jaxpr.eqns) == len(calls) == 1
+        assert calls[0].params["name"] == "_share_cum"
+
     def test_prior_dominates_pre_launch(self):
         """Before launch the data carries ~no signal, so a prior-weighted
         fit must reproduce the announced curve; a data-only fit must not

@@ -448,8 +448,9 @@ class TestTelemetryOverhead:
 #: The planner's host stages, in the order a plan opens them.
 STAGES = (
     "replan", "replan/scenarios", "replan/place_rows", "replan/prepare",
-    "replan/scan", "replan/pull", "replan/post", "replan/post/books",
-    "replan/post/totals", "replan/post/report", "replan/post/baselines",
+    "replan/prepare/forecast", "replan/scan", "replan/pull", "replan/post",
+    "replan/post/books", "replan/post/totals", "replan/post/report",
+    "replan/post/baselines",
 )
 
 
@@ -492,6 +493,16 @@ class TestStages:
             if ev.name == "replan" or ev.name.startswith("replan/")
         ]
         return sorted(out, key=lambda e: (e[1], -e[2]))
+
+    def _prefix_bytes(self, pools):
+        """Bytes of the forecaster's prefix state for the replay's rows:
+        the design over history and horizon, the weekly prefix Grams and
+        the rows' right-hand sides, float32, D = 31 columns (a 12-week
+        history keeps the yearly terms off)."""
+        d, hours = 31, self.WEEKS * 168
+        rows = self.N * pools.num_pools
+        return 4 * (d * (hours + 4 * 168) + self.WEEKS * d * d
+                    + rows * self.WEEKS * d)
 
     def _draw_bytes(self, pools):
         """Bytes of the generator's draws for the replay's batch, each
@@ -546,11 +557,13 @@ class TestStages:
             assert tuple(e[0] for e in plan) == names
             by_name = {e[0]: e for e in plan}
             for name, start, end, _ in plan[1:]:
-                parent = by_name["replan/post" if name.startswith(
-                    "replan/post/") else "replan"]
+                parent = by_name[name.rsplit("/", 1)[0]]
                 assert parent[1] <= start <= end <= parent[2], name
-            for a, b in zip(plan[2:6], plan[3:7]):
+            top = [e for e in plan if e[0].count("/") == 1]
+            for a, b in zip(top[1:5], top[2:6]):
                 assert a[2] <= b[1], (a[0], b[0])   # siblings in sequence
+            assert by_name["replan/prepare/forecast"][3] == {
+                "prefix_bytes": self._prefix_bytes(pools)}
             assert by_name["replan/scenarios"][3] == {
                 "device_rows": self.N * pools.num_pools}
             assert by_name["replan/place_rows"][3] == {
@@ -577,6 +590,8 @@ class TestStages:
             "replan/scenarios": {"device_rows": self.N * pools.num_pools},
             "replan/place_rows": {
                 "h2d_bytes": realized + self._draw_bytes(pools)},
+            "replan/prepare/forecast": {
+                "prefix_bytes": self._prefix_bytes(pools)},
             "replan/pull": {"d2h_bytes": self._pulled_bytes(rep)},
             "replan/post/baselines": {
                 "d2h_bytes": self.N * pools.num_pools
@@ -607,6 +622,40 @@ class TestStages:
             pools.num_pools * hours + sum(a.size for a in draws))}
         assert counts["replan/scenarios"] == {
             "device_rows": self.N * pools.num_pools}
+
+    def test_forecast_stage_counts_the_prefix_state(self, monkeypatch):
+        """With migration on, ``prefix_bytes`` is the bytes of what the
+        stage builds: the design, the prefix Grams, the right-hand sides
+        and the share moments (read off the built states)."""
+        from repro.core import forecast as fc
+        from repro.core import migration as mg
+        from repro.obs import recording
+
+        built = []
+        for mod, name in ((fc, "prefix_fit_state"),
+                          (mg, "share_prefix_state")):
+            real = getattr(mod, name)
+
+            def spy(*a, _real=real, **k):
+                built.append(_real(*a, **k))
+                return built[-1]
+
+            monkeypatch.setattr(mod, name, spy)
+        pools = traces.synthetic_pool_set(
+            num_pools=4, num_hours=24 * 7 * self.WEEKS, migration=True)
+        rec = SpanRecorder()
+        with recording(rec):
+            replan.replan_fleet_pools(
+                pools, cadence_weeks=2, start_weeks=self.START,
+                horizon_weeks=4, compare=False, migration=True,
+            )
+        state, share = built
+        want = sum(int(a.nbytes) for a in (
+            state.x, state.gram_prefix, state.rhs_prefix, share.cum,
+            share.t_max))
+        (got,) = [sp.counts for sp in rec.spans
+                  if sp.name == "replan/prepare/forecast"]
+        assert got == {"prefix_bytes": want}
 
     def test_api_plan_brackets_the_replay(self, pools):
         """``api.plan`` wraps the replay in ``api/plan``, so what runs as

@@ -190,6 +190,11 @@ def _ridge_solve(gram: jnp.ndarray, rhs: jnp.ndarray, ridge: float):
     return _spd_solve(g, rhs.T).T
 
 
+@functools.partial(
+    jax.tree_util.register_dataclass,
+    data_fields=["x", "gram_prefix", "rhs_prefix", "logy"],
+    meta_fields=["cfg", "t_max", "num_hist_hours", "period_hours"],
+)
 @dataclasses.dataclass(frozen=True)
 class PrefixFitState:
     """Precomputed normal-equation state for *rolling* prefix re-fits.

@@ -163,7 +163,11 @@ class Decision(NamedTuple):
 
 
 class Policy:
-    """Base policy: subclass and implement :meth:`setup`."""
+    """Base policy: subclass and implement :meth:`setup`.
+
+    The replay keeps one compiled program per policy *value*, so a policy
+    with parameters is a frozen dataclass over them: equal parameters give
+    equal, equal-hashing policies, and reuse the program."""
 
     name: str = "policy"
     #: produces a forecast (yhat) — required by the spot / migration /
@@ -183,10 +187,8 @@ class Policy:
             return (w - ctx.start_weeks) % ctx.cadence_weeks == 0
         return w == ctx.start_weeks
 
-    def __repr__(self) -> str:  # pragma: no cover - cosmetic
-        return f"{type(self).__name__}()"
 
-
+@dataclasses.dataclass(frozen=True)
 class RollingPortfolioPolicy(Policy):
     """The paper's rolling loop as a policy: re-fit the forecaster on the
     week-``w`` prefix (one gather + ridge solve against the cumulative
@@ -283,6 +285,7 @@ class RollingPortfolioPolicy(Policy):
         return pstate0, decide
 
 
+@dataclasses.dataclass(frozen=True)
 class OneShotPolicy(RollingPortfolioPolicy):
     """Degenerate rolling policy: one decision at the start week, then
     tranches only expire — what ``plan_fleet_pools`` prices at t0."""
@@ -293,6 +296,7 @@ class OneShotPolicy(RollingPortfolioPolicy):
         return w == ctx.start_weeks
 
 
+@dataclasses.dataclass(frozen=True)
 class HindsightPolicy(Policy):
     """Non-causal reference: the optimal *constant* stack on the realized
     evaluation demand (billing lines, ``term_weighting=0``), held every
@@ -326,6 +330,7 @@ def _hedge_threshold(u: jnp.ndarray) -> jnp.ndarray:
     return jnp.log1p(u * (math.e - 1.0))
 
 
+@dataclasses.dataclass(frozen=True)
 class DeterministicHedgePolicy(Policy):
     """Ambati et al.'s break-even hedging rule per capacity band.
 
@@ -344,15 +349,20 @@ class DeterministicHedgePolicy(Policy):
     name = "deterministic_hedge"
     needs_prev_demand = True
 
-    def __init__(self, grid_size: int = 32, top_multiplier: float = 1.5):
-        if grid_size < 1:
-            raise ValueError(f"grid_size must be >= 1, got {grid_size}")
-        if top_multiplier <= 0:
+    grid_size: int = 32
+    top_multiplier: float = 1.5
+
+    def __post_init__(self):
+        if self.grid_size < 1:
+            raise ValueError(f"grid_size must be >= 1, got {self.grid_size}")
+        if self.top_multiplier <= 0:
             raise ValueError(
-                f"top_multiplier must be > 0, got {top_multiplier}"
+                f"top_multiplier must be > 0, got {self.top_multiplier}"
             )
-        self.grid_size = int(grid_size)
-        self.top_multiplier = float(top_multiplier)
+        object.__setattr__(self, "grid_size", int(self.grid_size))
+        object.__setattr__(
+            self, "top_multiplier", float(self.top_multiplier)
+        )
 
     def _thresholds(self, num_pools: int) -> jnp.ndarray:
         return jnp.ones((num_pools, self.grid_size), jnp.float32)
@@ -371,6 +381,12 @@ class DeterministicHedgePolicy(Policy):
         top = jnp.maximum(hist.max(-1), 1e-6) * self.top_multiplier  # (P,)
         dg = top / g
         levels = dg[:, None] * jnp.arange(g, dtype=jnp.float32)[None, :]
+        # Each band's upper edge in one rounding, not as ``levels + dg``:
+        # the compiled replay hoists that sum out of its loop and fuses it
+        # with the multiply into one rounding, so a band's edge would sit
+        # an ulp off where the op-by-op replay puts it, and a band that
+        # the stack top just covers would keep accruing.
+        uppers = dg[:, None] * jnp.arange(1, g + 1, dtype=jnp.float32)
         # One designated SKU per pool: cheapest rate available on its
         # cloud (ski rental hedges od vs ONE buy price; portfolio mixing
         # is the forecasting planner's game).
@@ -400,9 +416,7 @@ class DeterministicHedgePolicy(Policy):
         def decide(pstate, obs: Observation):
             accrued = pstate
             stack_top = obs.active.sum(-1)                       # (P,)
-            covered = (
-                levels + dg[:, None] <= stack_top[:, None] + 1e-6
-            )
+            covered = uppers <= stack_top[:, None] + 1e-6
             spend = self._band_spend(obs.d_prev, levels, dg, ctx.od)
             accrued = jnp.where(covered, accrued, accrued + spend)
             commit = ~covered & (accrued >= z * band_price[:, None])
@@ -416,6 +430,7 @@ class DeterministicHedgePolicy(Policy):
         return a0, decide
 
 
+@dataclasses.dataclass(frozen=True)
 class RandomizedHedgePolicy(DeterministicHedgePolicy):
     """The randomized variant: each band draws its own threshold ``z``
     from the density e^z/(e-1) on (0, 1] at setup, lowering the expected
@@ -423,14 +438,11 @@ class RandomizedHedgePolicy(DeterministicHedgePolicy):
 
     name = "randomized_hedge"
 
-    def __init__(
-        self,
-        grid_size: int = 32,
-        top_multiplier: float = 1.5,
-        seed: int = 0,
-    ):
-        super().__init__(grid_size=grid_size, top_multiplier=top_multiplier)
-        self.seed = int(seed)
+    seed: int = 0
+
+    def __post_init__(self):
+        super().__post_init__()
+        object.__setattr__(self, "seed", int(self.seed))
 
     def _thresholds(self, num_pools: int) -> jnp.ndarray:
         u = jax.random.uniform(

@@ -397,30 +397,24 @@ class TestTelemetryOverhead:
         pools = traces.synthetic_pool_set(num_pools=2, num_hours=24 * 7 * 10)
         kw = dict(cadence_weeks=2, start_weeks=4, horizon_weeks=4,
                   compare=False)
-        real_scan = jax.lax.scan
+        real = replan._replay_program
 
         def scan_of(**extra):
             """(output shapes, flops, d2h_bytes) of the replay's scan."""
             calls = []
 
-            def spy(f, init, xs=None, *a, **k):
-                leaves = jax.tree.leaves((init, xs))
-                if not any(isinstance(x, jax.core.Tracer) for x in leaves):
-                    calls.append((f, init, xs, a, k))
-                return real_scan(f, init, xs, *a, **k)
+            def spy(*a):
+                calls.append(a)
+                return real(*a)
 
-            monkeypatch.setattr(jax.lax, "scan", spy)
+            monkeypatch.setattr(replan, "_replay_program", spy)
             rec = SpanRecorder()
             with recording(rec):
                 replan.replan_fleet_pools(pools, **kw, **extra)
-            monkeypatch.setattr(jax.lax, "scan", real_scan)
-            (f, init, xs, a, k), = calls
-
-            def whole(c, x):
-                return real_scan(f, c, x, *a, **k)
-
-            outs = jax.eval_shape(whole, init, xs)[1]
-            flops = jax.jit(whole).lower(init, xs).cost_analysis()["flops"]
+            monkeypatch.setattr(replan, "_replay_program", real)
+            (a,) = calls
+            outs = real.eval_shape(*a)
+            flops = real.lower(*a).cost_analysis()["flops"]
             (pull,) = [sp for sp in rec.spans if sp.name == "replan/pull"]
             return outs, flops, pull.counts["d2h_bytes"]
 
@@ -448,10 +442,13 @@ class TestTelemetryOverhead:
 #: The planner's host stages, in the order a plan opens them.
 STAGES = (
     "replan", "replan/scenarios", "replan/place_rows", "replan/prepare",
-    "replan/prepare/forecast", "replan/scan", "replan/pull", "replan/post",
-    "replan/post/books", "replan/post/totals", "replan/post/report",
-    "replan/post/baselines",
+    "replan/prepare/forecast", "replan/scan", "replan/scan/lower",
+    "replan/pull", "replan/post", "replan/post/books", "replan/post/totals",
+    "replan/post/report", "replan/post/baselines",
+    "replan/post/baselines/lower",
 )
+#: The stages of a plan without baselines (``compare=False``).
+PLAIN = STAGES[:-2]
 
 
 class TestStages:
@@ -548,7 +545,7 @@ class TestStages:
         assert len(roots) == 2
         realized = pools.num_pools * self.WEEKS * 168 * 4
         for lo, hi, rep, names in (
-            (roots[0], roots[1], plain, STAGES[:-1]),
+            (roots[0], roots[1], plain, PLAIN),
             (roots[1], len(events), full, STAGES),
         ):
             plan = events[lo:hi]
@@ -570,6 +567,8 @@ class TestStages:
                 "h2d_bytes": realized + self._draw_bytes(pools)}
             assert by_name["replan/pull"][3] == {
                 "d2h_bytes": self._pulled_bytes(rep)}
+            # The replay was compiled outside the trace, and is kept.
+            assert by_name["replan/scan/lower"][3] == {"scan_lowerings": 0}
         # The scan's rows are built on the device, not placed from the host.
         assert placed[-1] == self.N * realized
         eval_bytes = (self.N * pools.num_pools
@@ -580,6 +579,7 @@ class TestStages:
     def test_recorder_gets_the_same_stages_and_counts(self, pools):
         from repro.obs import recording
 
+        self._plan(pools, compare=True)    # compile both replays first
         rec = SpanRecorder()
         with recording(rec):
             rep = self._plan(pools, compare=True)
@@ -592,7 +592,9 @@ class TestStages:
                 "h2d_bytes": realized + self._draw_bytes(pools)},
             "replan/prepare/forecast": {
                 "prefix_bytes": self._prefix_bytes(pools)},
+            "replan/scan/lower": {"scan_lowerings": 0},
             "replan/pull": {"d2h_bytes": self._pulled_bytes(rep)},
+            "replan/post/baselines/lower": {"scan_lowerings": 0},
             "replan/post/baselines": {
                 "d2h_bytes": self.N * pools.num_pools
                 * (self.WEEKS - self.START) * 168 * 4},
@@ -672,7 +674,7 @@ class TestStages:
                                           start_weeks=self.START,
                                           compare=False),
             ))
-        assert [sp.name for sp in rec.spans] == ["api/plan", *STAGES[:-1]]
+        assert [sp.name for sp in rec.spans] == ["api/plan", *PLAIN]
         assert [sp.depth for sp in rec.spans[:3]] == [0, 1, 2]
 
     def test_idle_stage_reads_no_clock(self, pools, monkeypatch):

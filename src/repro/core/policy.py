@@ -3,9 +3,10 @@
 The weekly replay in ``repro.core.replan`` is a harness: roll expired
 tranches off, let a *policy* pick this week's per-pool target stack, buy
 only the increments, bill the week.  This module owns the policy side of
-that contract so alternative purchasing strategies can ride the same
-``lax.scan`` — and the same tournament rig (``repro.core.tournament``) —
-without touching the harness:
+that contract so alternative purchasing strategies ride the same
+``lax.scan`` step without touching the harness.  The planner and the
+tournament (``repro.core.tournament``) both run that one step, and the
+harness builds the context a policy sees:
 
     RollingPortfolioPolicy   the paper's Algorithm 1 loop: weekly prefix
                              refit -> per-horizon thresholds -> monotone
@@ -60,7 +61,6 @@ import jax.numpy as jnp
 from repro.core import forecast as fc
 from repro.core import portfolio as pf
 from repro.core.demand import HOURS_PER_WEEK
-from repro.core.planner import _monotone_stack, _prefix_weighted_quantiles
 
 # Competitive-ratio guarantees from Ambati et al. (arXiv 2004.04302):
 # break-even deterministic ski rental is 2-competitive; the randomized
@@ -73,13 +73,13 @@ RANDOMIZED_CR_BOUND = math.e / (math.e - 1.0)
 class PolicyContext:
     """Everything a policy may consult, assembled once per replay.
 
-    Built by ``replan.replan_fleet_pools`` (full harness: spot floors,
-    migration recomposition and the grid solver ride in ``targets_for``
-    and ``compose_forecast``) or by :func:`make_context` (the lean
-    tournament variant: quantile solver only).  All array members are
-    JAX arrays so the whole context can be closed over inside a traced
-    program; ``solve_fn``/``targets_for``/``compose_forecast`` are
-    trace-time callables, not runtime data."""
+    Built in one place, ``replan._policy_context``, for every replay the
+    planner and the tournament run: spot floors, migration
+    recomposition and the grid solver ride in ``targets_for`` and
+    ``compose_forecast``.  All array members are JAX arrays so the whole
+    context can be closed over inside a traced program;
+    ``solve_fn``/``targets_for``/``compose_forecast`` are trace-time
+    callables, not runtime data."""
 
     demand: jnp.ndarray          # (P, T) whole-week demand, history + eval
     options: list
@@ -306,16 +306,10 @@ class HindsightPolicy(Policy):
     name = "hindsight"
 
     def setup(self, ctx: PolicyContext):
-        al0, be0, _ = pf.pool_option_lines(
-            ctx.options, ctx.clouds, term_weighting=0.0, od_rate=ctx.od
-        )
-        eval_demand = ctx.demand[:, ctx.start_weeks * HOURS_PER_WEEK:]
-        plan = jax.vmap(
-            lambda f_, a_, b_: pf.optimal_portfolio_stack(
-                f_, a_, b_, od_rate=ctx.od
-            )
-        )(eval_demand, al0, be0)
-        widths = plan.widths                                   # (P, K)
+        widths = pf.hindsight_stack(
+            ctx.demand[:, ctx.start_weeks * HOURS_PER_WEEK:], ctx.options,
+            ctx.clouds, od_rate=ctx.od,
+        )                                                      # (P, K)
 
         def decide(pstate, obs: Observation):
             is_dec = jnp.asarray(True)
@@ -476,61 +470,3 @@ def get_policy(policy: "Policy | str | None") -> Policy:
             ) from None
     raise TypeError(f"policy must be a Policy, name or None, got {policy!r}")
 
-
-def make_context(
-    demand: jnp.ndarray,
-    options: list | None = None,
-    *,
-    clouds: tuple[str, ...],
-    od_rate: float,
-    term_weighting: float = 0.0,
-    cfg: fc.ForecastConfig = fc.ForecastConfig(),
-    start_weeks: int,
-    cadence_weeks: int = 1,
-    horizon_weeks: int = 8,
-    solve_fn: Callable | None = None,
-) -> PolicyContext:
-    """The lean context the tournament rig runs policies against: the
-    shared-sort quantile solver only (no spot band, no migration, no
-    grid sweep), fully traceable so one context per demand path can be
-    built *inside* a vmapped program.  ``replan_fleet_pools`` builds the
-    full-harness equivalent from its own closures."""
-    options = options if options is not None else pf.options_from_pricing()
-    demand = jnp.asarray(demand, jnp.float32)
-    total_weeks = demand.shape[-1] // HOURS_PER_WEEK
-    demand = demand[:, : total_weeks * HOURS_PER_WEEK]
-    horizon_hours = horizon_weeks * HOURS_PER_WEEK
-    al, be, avail = pf.pool_option_lines(
-        options, clouds, term_weighting=term_weighting, od_rate=od_rate
-    )
-    qs = jax.vmap(
-        lambda a_, b_: pf.handover_fractiles(a_, b_, od_rate=od_rate)
-    )(al, be)
-    rates = jnp.asarray([o.rate for o in options], jnp.float32)
-    term_weeks = jnp.asarray([o.term_weeks for o in options], jnp.int32)
-    w_hours = jnp.arange(1, horizon_weeks + 1) * HOURS_PER_WEEK
-    state = fc.prefix_fit_state(
-        demand, cfg, horizon_hours=horizon_hours,
-        min_prefix_hours=start_weeks * HOURS_PER_WEEK,
-    )
-
-    def targets_for(yhat):
-        per_h = jax.vmap(
-            lambda y, q: _prefix_weighted_quantiles(y, w_hours, q)
-        )(yhat, qs)
-        widths, _ = jax.vmap(
-            lambda ph, q: _monotone_stack(
-                ph, q, term_weeks, horizon_weeks
-            )
-        )(per_h, qs)
-        return widths, None
-
-    return PolicyContext(
-        demand=demand, options=options, clouds=tuple(clouds), od=od_rate,
-        rates=rates, term_weeks=term_weeks, avail=avail, qs=qs,
-        w_hours=w_hours, start_weeks=start_weeks,
-        cadence_weeks=cadence_weeks, horizon_weeks=horizon_weeks,
-        total_weeks=total_weeks, state=state,
-        solve_fn=solve_fn if solve_fn is not None else fc.solve_prefix,
-        targets_for=targets_for,
-    )

@@ -510,6 +510,29 @@ def optimal_portfolio_stack(
     )
 
 
+def hindsight_stack(
+    eval_demand: jnp.ndarray,
+    options: Sequence[PurchaseOption],
+    clouds: Sequence[str],
+    *,
+    od_rate: float,
+) -> jnp.ndarray:
+    """The hindsight reference: each row's optimal *constant* stack
+    widths (R, K) on its realized evaluation demand ``eval_demand``
+    (R, S*168), pool ``r`` on ``clouds[r]``.  It prices the billing lines
+    (``term_weighting=0``: every active tranche bills its rate, expiring
+    tranches are rebought back to back).  It adds no ``jit`` of its own,
+    so it runs the same ops eagerly and inside a caller's program."""
+    al0, be0, _ = pool_option_lines(
+        options, clouds, term_weighting=0.0, od_rate=od_rate
+    )
+    return jax.vmap(
+        lambda f_, a_, b_: optimal_portfolio_stack(
+            f_, a_, b_, od_rate=od_rate
+        )
+    )(eval_demand, al0, be0).widths
+
+
 def portfolio_cost(
     f: jnp.ndarray,
     levels: jnp.ndarray,

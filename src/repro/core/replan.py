@@ -31,6 +31,12 @@ re-accumulated prefix fit and one Python dispatch per week — kept as the
 benchmark baseline (``bench_rolling_replan``) and as an independent
 implementation the scan path is tested against.
 
+A replayed week is defined here once: the step (``_replay_step``) and
+the context its policy sees (``_policy_context``).  ``_replay_inputs``
+builds a band-free replay's spec and arrays from demand rows, eagerly or
+under ``jit(vmap)``, and the policy tournament (``core.tournament``) runs
+each of its paths through this same step.
+
 The report compares three operating points on the same evaluation window:
 
     rolling    — the replay above;
@@ -38,9 +44,10 @@ The report compares three operating points on the same evaluation window:
                  t0 plan, then let tranches expire; what
                  ``plan_fleet_pools`` prices today);
     hindsight  — the optimal *constant* stack computed on the realized
-                 demand (``portfolio.optimal_portfolio_stack`` per pool,
-                 full knowledge; short-term tranches assumed repurchased
-                 back-to-back).
+                 demand (``portfolio.hindsight_stack``, full knowledge;
+                 short-term tranches assumed repurchased back-to-back),
+                 the one reference ``HindsightPolicy`` and the tournament
+                 score against too.
 
 ``solver="grid"`` routes each week's per-horizon prefix solves through the
 ``commitment_sweep`` over/under sweep on 0/1 prefix-mask weights (the
@@ -389,16 +396,11 @@ class _ReplaySpec:
     settings or its shapes; a value computed from demand is never here,
     since the program would then be traced anew for every plan (and a
     program reused for another plan would answer with the first one's
-    data)."""
+    data).  The settings after ``num_scen`` default to a band-free,
+    quantile-solved weekly replay (:func:`_replay_lines`)."""
 
     policy: pol.Policy
     cadence_weeks: int          # 0: the one-shot baseline's single decision
-    mode: str                   # "weekly" or "breach"
-    solver: str
-    num_grid: int
-    use_kernel: bool
-    irls_iters: int
-    irls_carry: bool
     horizon_weeks: int
     start_weeks: int
     total_weeks: int
@@ -407,12 +409,18 @@ class _ReplaySpec:
     clouds: tuple               # the cloud of each pool of one scenario
     od: float
     num_scen: int               # scenario blocks on the row axis
-    spot: bool
-    migration: bool
-    convertible: bool
-    telemetry: "obs_config.TelemetryConfig | None"
-    breach_band: tuple
-    breach_tolerance: float
+    mode: str = "weekly"        # "weekly" or "breach"
+    solver: str = "quantile"
+    num_grid: int = 128
+    use_kernel: bool = False
+    irls_iters: int = 0
+    irls_carry: bool = False
+    spot: bool = False
+    migration: bool = False
+    convertible: bool = False
+    telemetry: "obs_config.TelemetryConfig | None" = None
+    breach_band: tuple = (0.05, 0.95)
+    breach_tolerance: float = 4.0
 
 
 class _ReplayArrays(NamedTuple):
@@ -439,6 +447,58 @@ class _ReplayArrays(NamedTuple):
     conv_rates: jnp.ndarray | None = None   # (Kc,)
 
 
+def _replay_lines(demand, options, clouds, *, od, term_weighting,
+                  start_weeks, horizon_weeks, cadence_weeks, policy):
+    """The spec and arrays of a band-free replay of ``policy`` over the
+    demand rows ``demand`` (R, T in whole weeks), each block of
+    ``len(clouds)`` rows one scenario on ``clouds``: the option lines,
+    handover fractiles, rates, terms and horizon hours.  The prefix state
+    is left None for :func:`_prefix_state`.  It places nothing and opens
+    no stage, so it runs eagerly and under ``jit(vmap)`` alike."""
+    num_scen = demand.shape[0] // len(clouds)
+    total_weeks = demand.shape[-1] // HOURS_PER_WEEK
+    al, be, avail = pf.pool_option_lines(
+        options, tuple(clouds) * num_scen, term_weighting=term_weighting,
+        od_rate=od,
+    )
+    qs = jax.vmap(
+        functools.partial(pf.handover_fractiles, od_rate=od)
+    )(al, be)                                                   # (R, K)
+    spec = _ReplaySpec(
+        policy=policy, cadence_weeks=cadence_weeks,
+        horizon_weeks=horizon_weeks, start_weeks=start_weeks,
+        total_weeks=total_weeks,
+        sched_len=total_weeks + max(o.term_weeks for o in options) + 1,
+        options=tuple(options), clouds=tuple(clouds), od=float(od),
+        num_scen=num_scen,
+    )
+    arrays = _ReplayArrays(
+        demand=demand, state=None,
+        rates=jnp.asarray([o.rate for o in options], jnp.float32),
+        term_weeks=jnp.asarray([o.term_weeks for o in options], jnp.int32),
+        avail=avail, qs=qs, al=al, be=be,
+        w_hours=jnp.arange(1, horizon_weeks + 1) * HOURS_PER_WEEK,
+    )
+    return spec, arrays
+
+
+def _prefix_state(fit_demand, cfg: fc.ForecastConfig, spec: _ReplaySpec):
+    """The forecaster's prefix state on ``fit_demand`` for ``spec``'s
+    horizon, from its first replayed week on."""
+    return fc.prefix_fit_state(
+        fit_demand, cfg, horizon_hours=spec.horizon_weeks * HOURS_PER_WEEK,
+        min_prefix_hours=spec.start_weeks * HOURS_PER_WEEK,
+    )
+
+
+def _replay_inputs(demand, options, clouds, *, cfg, **lines):
+    """Spec and arrays of a band-free replay, prefix state included:
+    :func:`_replay_lines` (``lines`` are its keywords), then
+    :func:`_prefix_state` of ``cfg`` on ``demand``."""
+    spec, arrays = _replay_lines(demand, options, clouds, **lines)
+    return spec, arrays._replace(state=_prefix_state(demand, cfg, spec))
+
+
 def _pool_to_cloud(v, member, num_scen: int):
     """Aggregate per-pool rows (R, ...) onto the per-scenario cloud rows
     (N*C, ...) — block-diagonal membership without a widened contraction,
@@ -451,40 +511,34 @@ def _pool_to_cloud(v, member, num_scen: int):
     return out.reshape(num_scen * member.shape[0], *v.shape[1:])
 
 
-def _replay_step(spec: _ReplaySpec, a: _ReplayArrays, zeros, solve_fn):
-    """The replay's scan step and its initial carry, built from ``spec``,
-    the arrays ``a`` and the carried stacks' initial ``zeros`` alone.
-    ``solve_fn`` is the prefix refit: ``fc.solve_prefix`` in the compiled
-    scan, ``fc.solve_prefix_direct`` in the Python-loop reference."""
+def _grid_prefix_levels(spec: _ReplaySpec, a: _ReplayArrays, yhat, al, be):
+    """Per-horizon stack tops via the over/under sweep on prefix-mask
+    weights: horizon prefixes fold into the row axis so the whole
+    (R x Wh, H, G) problem is one batched sweep (rows = pools for the
+    standard options, clouds for the convertible residual)."""
+    horizon_weeks = spec.horizon_weeks
+    num_rows, num_k = yhat.shape[0], al.shape[-1]
+    f_rep = jnp.repeat(yhat, horizon_weeks, axis=0)        # (R*Wh, H)
+    t = jnp.arange(horizon_weeks * HOURS_PER_WEEK)
+    masks = (t[None, :] < a.w_hours[:, None]).astype(yhat.dtype)
+    w_rep = jnp.tile(masks, (num_rows, 1))
+    plan = pf.optimal_portfolio_grid(
+        f_rep,
+        jnp.repeat(al, horizon_weeks, axis=0),
+        jnp.repeat(be, horizon_weeks, axis=0),
+        od_rate=spec.od, num_grid=spec.num_grid, use_kernel=spec.use_kernel,
+        weights=w_rep,
+    )
+    return plan.levels.reshape(num_rows, horizon_weeks, num_k)
+
+
+def _policy_context(spec: _ReplaySpec, a: _ReplayArrays, solve_fn):
+    """The one :class:`~repro.core.policy.PolicyContext` every replay sets
+    its policy up against: ``targets_for`` carries the configured solver
+    (quantile or grid sweep) and the spot floors, ``compose_forecast``
+    the migration recomposition.  ``solve_fn`` is the prefix refit."""
     horizon_weeks = spec.horizon_weeks
     horizon_hours = horizon_weeks * HOURS_PER_WEEK
-    num_rows, num_opts = a.demand.shape[0], len(spec.options)
-    num_scen, num_pools = spec.num_scen, len(spec.clouds)
-    od, tele = spec.od, spec.telemetry
-    demand_wk = a.demand.reshape(
-        num_rows, spec.total_weeks, HOURS_PER_WEEK
-    )
-    if spec.convertible:
-        num_clouds, num_conv = a.member.shape[0], a.conv_rates.shape[0]
-        num_cloud_rows = num_clouds * num_scen
-
-    def grid_prefix_levels(yhat, al, be, num_rows, num_k):
-        """Per-horizon stack tops via the over/under sweep on prefix-mask
-        weights: horizon prefixes fold into the row axis so the whole
-        (R x Wh, H, G) problem is one batched sweep (rows = pools for the
-        standard options, clouds for the convertible residual)."""
-        f_rep = jnp.repeat(yhat, horizon_weeks, axis=0)    # (R*Wh, H)
-        t = jnp.arange(horizon_hours)
-        masks = (t[None, :] < a.w_hours[:, None]).astype(yhat.dtype)
-        w_rep = jnp.tile(masks, (num_rows, 1))
-        plan = pf.optimal_portfolio_grid(
-            f_rep,
-            jnp.repeat(al, horizon_weeks, axis=0),
-            jnp.repeat(be, horizon_weeks, axis=0),
-            od_rate=od, num_grid=spec.num_grid, use_kernel=spec.use_kernel,
-            weights=w_rep,
-        )
-        return plan.levels.reshape(num_rows, horizon_weeks, num_k)
 
     def spot_floors_for(yhat):
         """(P, W) per-horizon spot floors on one week's forecast: the
@@ -511,7 +565,7 @@ def _replay_step(spec: _ReplaySpec, a: _ReplayArrays, zeros, solve_fn):
         (horizon 1 — spot is re-decided weekly, so only the nearest
         horizon binds it) rides along as the fast-capacity decision."""
         if spec.solver == "grid":
-            per_h = grid_prefix_levels(yhat, a.al, a.be, num_rows, num_opts)
+            per_h = _grid_prefix_levels(spec, a, yhat, a.al, a.be)
         else:
             per_h = jax.vmap(
                 lambda y, q: _prefix_weighted_quantiles(y, a.w_hours, q)
@@ -525,6 +579,48 @@ def _replay_step(spec: _ReplaySpec, a: _ReplayArrays, zeros, solve_fn):
             lambda ph, q: _monotone_stack(ph, q, a.term_weeks, horizon_weeks)
         )(per_h, a.qs)
         return widths, floor
+
+    # Migration recomposition as the policy hook: pair totals x rolling
+    # logit-share fits become per-pool forecasts (the share state solves
+    # on the same week prefix the structural fit did).
+    if spec.migration:
+        def compose_forecast(yhat, w):
+            sa, sb = mg.solve_share_prefix(a.share_state, w)
+            t_fut = w * HOURS_PER_WEEK + jnp.arange(horizon_hours)
+            sh = mg.predict_share(sa, sb, t_fut, a.share_state.t_max)
+            return mg.compose_forecast(yhat, sh, a.edges)
+    else:
+        compose_forecast = None
+
+    return pol.PolicyContext(
+        demand=a.demand, options=list(spec.options),
+        clouds=spec.clouds * spec.num_scen, od=spec.od, rates=a.rates,
+        term_weeks=a.term_weeks, avail=a.avail, qs=a.qs, w_hours=a.w_hours,
+        start_weeks=spec.start_weeks, cadence_weeks=spec.cadence_weeks,
+        horizon_weeks=horizon_weeks, total_weeks=spec.total_weeks,
+        state=a.state, solve_fn=solve_fn, irls_iters=spec.irls_iters,
+        irls_carry=spec.irls_carry, targets_for=targets_for,
+        compose_forecast=compose_forecast, cadence_mode=spec.mode,
+        breach_band=spec.breach_band,
+        breach_tolerance=spec.breach_tolerance,
+        scenario_blocks=spec.num_scen,
+    )
+
+
+def _replay_step(spec: _ReplaySpec, a: _ReplayArrays, zeros, solve_fn):
+    """The replay's scan step and its initial carry, built from ``spec``,
+    the arrays ``a`` and the carried stacks' initial ``zeros`` alone.
+    ``solve_fn`` is the prefix refit: ``fc.solve_prefix`` in the compiled
+    scan, ``fc.solve_prefix_direct`` in the Python-loop reference."""
+    horizon_weeks = spec.horizon_weeks
+    num_rows = a.demand.shape[0]
+    num_scen, num_pools = spec.num_scen, len(spec.clouds)
+    od, tele = spec.od, spec.telemetry
+    demand_wk = a.demand.reshape(
+        num_rows, spec.total_weeks, HOURS_PER_WEEK
+    )
+    if spec.convertible:
+        num_clouds = a.member.shape[0]
 
     def conv_targets_for(yhat, pool_top):
         """Cloud-level convertible targets on one week's forecast.
@@ -541,8 +637,8 @@ def _replay_step(spec: _ReplaySpec, a: _ReplayArrays, zeros, solve_fn):
         migrates."""
         total_c = _pool_to_cloud(yhat, a.member, num_scen)   # (C, H)
         if spec.solver == "grid":
-            per_h = grid_prefix_levels(
-                total_c, a.conv_al, a.conv_be, num_cloud_rows, num_conv
+            per_h = _grid_prefix_levels(
+                spec, a, total_c, a.conv_al, a.conv_be
             )
         else:
             per_h = jax.vmap(
@@ -555,34 +651,8 @@ def _replay_step(spec: _ReplaySpec, a: _ReplayArrays, zeros, solve_fn):
             tops_c, widths_c, _pool_to_cloud(pool_top, a.member, num_scen)
         )                                                    # (C, Kc)
 
-    # Migration recomposition as the policy hook: pair totals x rolling
-    # logit-share fits become per-pool forecasts (the share state solves
-    # on the same week prefix the structural fit did).
-    if spec.migration:
-        def compose_forecast(yhat, w):
-            sa, sb = mg.solve_share_prefix(a.share_state, w)
-            t_fut = w * HOURS_PER_WEEK + jnp.arange(horizon_hours)
-            sh = mg.predict_share(sa, sb, t_fut, a.share_state.t_max)
-            return mg.compose_forecast(yhat, sh, a.edges)
-    else:
-        compose_forecast = None
-
-    # The full-harness policy context: ``targets_for`` carries the
-    # configured solver (quantile or grid sweep) and the spot floors;
-    # ``compose_forecast`` the migration recomposition.
     step_policy, mode = spec.policy, spec.mode
-    pstate0, decide = step_policy.setup(pol.PolicyContext(
-        demand=a.demand, options=list(spec.options),
-        clouds=spec.clouds * num_scen, od=od, rates=a.rates,
-        term_weeks=a.term_weeks, avail=a.avail, qs=a.qs, w_hours=a.w_hours,
-        start_weeks=spec.start_weeks, cadence_weeks=spec.cadence_weeks,
-        horizon_weeks=horizon_weeks, total_weeks=spec.total_weeks,
-        state=a.state, solve_fn=solve_fn, irls_iters=spec.irls_iters,
-        irls_carry=spec.irls_carry, targets_for=targets_for,
-        compose_forecast=compose_forecast, cadence_mode=mode,
-        breach_band=spec.breach_band,
-        breach_tolerance=spec.breach_tolerance, scenario_blocks=num_scen,
-    ))
+    pstate0, decide = step_policy.setup(_policy_context(spec, a, solve_fn))
     needs_prev = step_policy.needs_prev_demand or mode == "breach"
     # The trailing realized window anchoring the fractile bands (spread
     # from realized hours, level from the forecast).  Only breach cadence
@@ -831,6 +901,18 @@ def scan(spec: _ReplaySpec, arrays: _ReplayArrays, zeros, ws):
     _replay_traces += 1
     step, carry0 = _replay_step(spec, arrays, zeros, fc.solve_prefix)
     return jax.lax.scan(step, carry0, ws)[1]
+
+
+def _replay_loop(spec: _ReplaySpec, arrays: _ReplayArrays, zeros, ws):
+    """The scan outputs of the naive replay: the step run from Python week
+    by week, with one full prefix re-accumulation per week (what the
+    compiled scan replaces) — the reference the scan is tested against."""
+    step, carry = _replay_step(spec, arrays, zeros, fc.solve_prefix_direct)
+    outs = []
+    for w in ws:
+        carry, out = step(carry, jnp.int32(w))
+        outs.append(out)
+    return {key: jnp.stack([o[key] for o in outs]) for key in outs[0]}
 
 
 #: The replay program the process keeps: traced and lowered once for each
@@ -1082,12 +1164,13 @@ def replan_fleet_pools(
         # Eager set-up dispatches ahead of the scan: option lines, handover
         # fractiles, the band set-ups and the forecaster's prefix state.
         with obs_spans.stage("replan/prepare"):
-            al_p, be_p, avail_p = pf.pool_option_lines(
-                options, row_clouds, term_weighting=term_weighting, od_rate=od
+            pcy = pol.get_policy(policy)
+            spec, arrays = _replay_lines(
+                demand, options, pools.clouds, od=od,
+                term_weighting=term_weighting, start_weeks=start_weeks,
+                horizon_weeks=horizon_weeks, cadence_weeks=cadence_weeks,
+                policy=pcy,
             )
-            qs = jax.vmap(
-                functools.partial(pf.handover_fractiles, od_rate=od)
-            )(al_p, be_p)                                              # (P, K)
             sp_res = spot_mod.resolve_spot(spot, row_clouds, od_rate=od)
             if sp_res is not None:
                 s_cfg, s_lines = sp_res
@@ -1095,11 +1178,7 @@ def replan_fleet_pools(
                     lambda a_, b_, r_: spot_mod.spot_entry_fractile(
                         a_, b_, r_, od_rate=od
                     )
-                )(al_p, be_p, s_lines.rate)                            # (P,)
-            rates = jnp.asarray([o.rate for o in options], jnp.float32)
-            term_weeks = jnp.asarray(
-                [o.term_weeks for o in options], jnp.int32
-            )
+                )(arrays.al, arrays.be, s_lines.rate)                  # (P,)
 
             # Migration awareness: the structural forecaster fits pair
             # totals (the old-family rows replaced by old + (1+uplift) x
@@ -1146,13 +1225,11 @@ def replan_fleet_pools(
                 conv_rates = jnp.asarray(
                     [o.rate for o in conv_opts], jnp.float32
                 )
-                max_term = max(int(term_weeks.max()), int(conv_terms.max()))
-            else:
-                max_term = int(term_weeks.max())
-            sched_len = total_weeks + max_term + 1
-            w_hours = jnp.arange(1, horizon_weeks + 1) * HOURS_PER_WEEK
+                # The roll-off schedule spans the longest term of either.
+                spec = dataclasses.replace(spec, sched_len=max(
+                    spec.sched_len, total_weeks + int(conv_terms.max()) + 1
+                ))
 
-            pcy = pol.get_policy(policy)
             if not pcy.forecasting:
                 bands = [
                     name for name, on in [
@@ -1189,10 +1266,7 @@ def replan_fleet_pools(
             with obs_spans.stage(
                 "replan/prepare/forecast", prefix_bytes=prefix_bytes
             ):
-                state = fc.prefix_fit_state(
-                    fit_demand, cfg, horizon_hours=horizon_hours,
-                    min_prefix_hours=start_weeks * HOURS_PER_WEEK,
-                )
+                state = _prefix_state(fit_demand, cfg, spec)
                 share_state = (
                     mg.share_prefix_state(
                         demand, edges, t_max=state.t_max,
@@ -1201,22 +1275,15 @@ def replan_fleet_pools(
                     if use_mig else None
                 )
 
-        spec = _ReplaySpec(
-            policy=pcy, cadence_weeks=cadence_weeks, mode=cadence,
-            solver=solver, num_grid=num_grid, use_kernel=use_kernel,
-            irls_iters=irls_iters, irls_carry=irls_carry,
-            horizon_weeks=horizon_weeks, start_weeks=start_weeks,
-            total_weeks=total_weeks, sched_len=sched_len,
-            options=tuple(options), clouds=tuple(pools.clouds), od=float(od),
-            num_scen=num_scen, spot=sp_res is not None, migration=use_mig,
-            convertible=conv_opts is not None, telemetry=tele,
-            breach_band=tuple(float(q) for q in breach_band),
+        spec = dataclasses.replace(
+            spec, mode=cadence, solver=solver, num_grid=num_grid,
+            use_kernel=use_kernel, irls_iters=irls_iters,
+            irls_carry=irls_carry, spot=sp_res is not None,
+            migration=use_mig, convertible=conv_opts is not None,
+            telemetry=tele, breach_band=tuple(float(q) for q in breach_band),
             breach_tolerance=float(breach_tolerance),
         )
-        arrays = _ReplayArrays(
-            demand=demand, state=state, rates=rates, term_weeks=term_weeks,
-            avail=avail_p, qs=qs, al=al_p, be=be_p, w_hours=w_hours,
-        )
+        arrays = arrays._replace(state=state)
         if sp_res is not None:
             arrays = arrays._replace(u_env=u_env, spot_lines=s_lines)
         if use_mig:
@@ -1234,14 +1301,16 @@ def replan_fleet_pools(
             out = (
                 jnp.zeros((num_rows, num_opts), jnp.float32,
                           device=demand.sharding),
-                jnp.zeros((num_rows, num_opts, sched_len), jnp.float32,
+                jnp.zeros((num_rows, num_opts, spec.sched_len), jnp.float32,
                           device=demand.sharding),
             )
             if conv_opts is None:
                 return out
             return out + (
                 jnp.zeros((num_cloud_rows, num_conv), jnp.float32),
-                jnp.zeros((num_cloud_rows, num_conv, sched_len), jnp.float32),
+                jnp.zeros(
+                    (num_cloud_rows, num_conv, spec.sched_len), jnp.float32
+                ),
             )
 
         with obs_spans.stage("replan/scan", phase="compile"):
@@ -1251,19 +1320,9 @@ def replan_fleet_pools(
                     jnp.arange(start_weeks, total_weeks),
                 )
             else:
-                # Naive python-level replay: one full prefix
-                # re-accumulation and one host dispatch per week (what the
-                # compiled scan replaces).
-                step, carry = _replay_step(
-                    spec, arrays, zeros(), fc.solve_prefix_direct
+                ys = _replay_loop(
+                    spec, arrays, zeros(), range(start_weeks, total_weeks)
                 )
-                outs = []
-                for w in range(start_weeks, total_weeks):
-                    carry, out = step(carry, jnp.int32(w))
-                    outs.append(out)
-                ys = {
-                    key: jnp.stack([o[key] for o in outs]) for key in outs[0]
-                }
         # The pull waits for the device, then copies every scan output.
         with obs_spans.stage(
             "replan/pull", phase="execute",
@@ -1569,18 +1628,10 @@ def replan_fleet_pools(
                 )
 
                 # Hindsight baseline: the optimal constant stack on realized
-                # demand (billing lines, i.e. term_weighting=0: every active
-                # tranche bills its rate; expiring short tranches are
-                # repurchased back-to-back).
-                al0, be0, _ = pf.pool_option_lines(
-                    options, row_clouds, term_weighting=0.0, od_rate=od
-                )
-                hs = jax.vmap(
-                    lambda f_, a_, b_: pf.optimal_portfolio_stack(
-                        f_, a_, b_, od_rate=od
-                    )
-                )(eval_demand, al0, be0)
-                hs_widths = np.asarray(hs.widths)
+                # demand, billed weekly on the host.
+                hs_widths = np.asarray(pf.hindsight_stack(
+                    eval_demand, options, row_clouds, od_rate=od
+                ))
                 hs_level = hs_widths.sum(-1)
                 ed_wk = np.asarray(eval_demand).reshape(
                     num_rows, len(weeks), HOURS_PER_WEEK
@@ -1589,7 +1640,8 @@ def replan_fleet_pools(
                     ed_wk - hs_level[:, None, None], 0.0
                 ).sum(-1)
                 hs_committed = (
-                    (np.asarray(rates) * hs_widths).sum(-1) * HOURS_PER_WEEK
+                    (np.asarray(arrays.rates) * hs_widths).sum(-1)
+                    * HOURS_PER_WEEK
                 )
                 hs_weekly = hs_committed[:, None] + od * hs_over      # (R, S)
                 report.hindsight_widths = hs_widths

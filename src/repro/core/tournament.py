@@ -20,17 +20,20 @@ distributions* instead of citations, and every future policy change has
 a scoreboard: tests pin the deterministic bound on steady fleets and the
 rolling planner's margin over both hedges on the declining fleet.
 
-The replay here is the lean commitments-only harness (no spot /
-migration / convertible bands — those key on the forecasting planner's
-weekly yhat): roll off expired tranches, let the policy decide, buy
-increments on decision weeks, bill committed rates plus on-demand
-overflow.  ``backend="loop"`` replays the same weeks as a Python loop
-(the scan-parity oracle, mirroring ``replan``'s loop backend).
+Each path runs the planner's own weekly step (``replan._replay_step``,
+built by ``replan._replay_inputs``) with no spot / migration /
+convertible band — those key on the forecasting planner's weekly yhat:
+roll off expired tranches, let the policy decide, buy increments on
+decision weeks, bill committed rates plus on-demand overflow.  The
+hindsight reference is ``portfolio.hindsight_stack``, the planner's
+own.  ``backend="loop"`` replays the same step as a Python loop with
+the direct prefix solve (``replan``'s loop backend).
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Literal, Sequence
 
 import jax
@@ -39,9 +42,9 @@ import numpy as np
 
 from repro.capacity import pricing
 from repro.core import forecast as fc
-from repro.core import ladder as ld
 from repro.core import policy as pol
 from repro.core import portfolio as pf
+from repro.core import replan as rp
 from repro.core.demand import HOURS_PER_WEEK
 from repro.data import scenarios as sc
 from repro.launch import mesh as mesh_mod
@@ -105,87 +108,45 @@ class TournamentReport:
         return "\n".join(rows)
 
 
-def _lean_replay(policy: pol.Policy, ctx: pol.PolicyContext, backend: str):
-    """Total replay cost of ``policy`` on ``ctx``'s demand path: the
-    commitments-only weekly harness (roll off, decide, buy increments,
-    bill) — the scan body of ``replan`` minus the optional bands."""
-    pstate0, decide = policy.setup(ctx)
-    num_p, num_k = ctx.num_pools, ctx.num_options
-    sched_len = ctx.total_weeks + int(max(
-        o.term_weeks for o in ctx.options
-    )) + 1
-    demand_wk = ctx.demand.reshape(
-        num_p, ctx.total_weeks, HOURS_PER_WEEK
+def _path_cost(policy: pol.Policy, demand, *, options, clouds, od, cfg,
+               start_weeks, cadence_weeks, horizon_weeks, backend):
+    """Total replay cost of ``policy`` on one (P, T) demand path, run
+    through the planner's own weekly step (``replan._replay_step``):
+    commitments only, quantile solver, billing lines."""
+    spec, arrays = rp._replay_inputs(
+        demand, options, clouds, od=od, term_weighting=0.0, cfg=cfg,
+        start_weeks=start_weeks, horizon_weeks=horizon_weeks,
+        cadence_weeks=cadence_weeks, policy=policy,
     )
-
-    def step(carry, w):
-        active, rolloff, pstate = carry
-        expired = jax.lax.dynamic_index_in_dim(
-            rolloff, w, axis=2, keepdims=False
-        )
-        active = active - expired
-        d_prev = (
-            jax.lax.dynamic_index_in_dim(
-                demand_wk, w - 1, axis=1, keepdims=False
-            )
-            if policy.needs_prev_demand else None
-        )
-        pstate, dec = decide(
-            pstate, pol.Observation(week=w, active=active, d_prev=d_prev)
-        )
-        inc = jnp.maximum(dec.targets - active, 0.0)
-        inc = jnp.where(
-            dec.is_decision & (inc > ld.PURCHASE_EPS), inc, 0.0
-        )
-        active = active + inc
-        expiry = jax.nn.one_hot(
-            w + ctx.term_weeks, sched_len, dtype=rolloff.dtype
-        )
-        rolloff = rolloff + inc[:, :, None] * expiry[None, :, :]
-        d = jax.lax.dynamic_index_in_dim(
-            demand_wk, w, axis=1, keepdims=False
-        )
-        level = active.sum(-1)
-        committed = (ctx.rates * active).sum(-1) * HOURS_PER_WEEK
-        over = jnp.maximum(d - level[:, None], 0.0).sum(-1)
-        return (active, rolloff, pstate), committed.sum() + ctx.od * over.sum()
-
-    carry0 = (
+    num_p, num_k = demand.shape[0], len(options)
+    zeros = (
         jnp.zeros((num_p, num_k), jnp.float32),
-        jnp.zeros((num_p, num_k, sched_len), jnp.float32),
-        pstate0,
+        jnp.zeros((num_p, num_k, spec.sched_len), jnp.float32),
     )
     if backend == "scan":
-        _, weekly = jax.lax.scan(
-            step, carry0, jnp.arange(ctx.start_weeks, ctx.total_weeks)
+        step, carry0 = rp._replay_step(spec, arrays, zeros, fc.solve_prefix)
+        ys = jax.lax.scan(
+            step, carry0, jnp.arange(start_weeks, spec.total_weeks)
+        )[1]
+    else:
+        ys = rp._replay_loop(
+            spec, arrays, zeros, range(start_weeks, spec.total_weeks)
         )
-        return weekly.sum()
-    carry, total = carry0, jnp.float32(0.0)
-    for w in range(ctx.start_weeks, ctx.total_weeks):
-        carry, cost = step(carry, jnp.int32(w))
-        total = total + cost
-    return total
+    return ys["committed"].sum() + ys["od"].sum()
 
 
 def _hindsight_cost(demand, *, options, clouds, od, start_weeks):
-    """Per-path hindsight optimum: the optimal constant stack on the
-    realized evaluation demand, billing lines (``term_weighting=0``) —
-    the exact reference ``replan_fleet_pools`` reports regret against."""
-    al0, be0, _ = pf.pool_option_lines(
-        options, clouds, term_weighting=0.0, od_rate=od
-    )
+    """Per-path hindsight optimum: the hindsight stack
+    (``portfolio.hindsight_stack``, the reference ``replan_fleet_pools``
+    reports regret against) billed over the evaluation weeks."""
     total_weeks = demand.shape[-1] // HOURS_PER_WEEK
     ev = demand[:, start_weeks * HOURS_PER_WEEK: total_weeks * HOURS_PER_WEEK]
-    plan = jax.vmap(
-        lambda f_, a_, b_: pf.optimal_portfolio_stack(
-            f_, a_, b_, od_rate=od
-        )
-    )(ev, al0, be0)
+    widths = pf.hindsight_stack(ev, options, clouds, od_rate=od)
     rates = jnp.asarray([o.rate for o in options], jnp.float32)
-    level = plan.widths.sum(-1)
+    level = widths.sum(-1)
     over = jnp.maximum(ev - level[:, None], 0.0).sum(-1)
     committed = (
-        (rates * plan.widths).sum(-1)
+        (rates * widths).sum(-1)
         * (total_weeks - start_weeks) * HOURS_PER_WEEK
     )
     return committed.sum() + od * over.sum()
@@ -242,19 +203,11 @@ def run_tournament(
         paths.reshape(num_f * num_seeds, num_pools, -1), jnp.float32
     ))
 
-    solve_fn = (
-        fc.solve_prefix if backend == "scan" else fc.solve_prefix_direct
+    replay = functools.partial(
+        _path_cost, options=options, clouds=clouds, od=od, cfg=cfg,
+        start_weeks=start_weeks, cadence_weeks=cadence_weeks,
+        horizon_weeks=horizon_weeks, backend=backend,
     )
-
-    def make_path_cost(policy):
-        def path_cost(demand):
-            ctx = pol.make_context(
-                demand, options, clouds=clouds, od_rate=od, cfg=cfg,
-                start_weeks=start_weeks, cadence_weeks=cadence_weeks,
-                horizon_weeks=horizon_weeks, solve_fn=solve_fn,
-            )
-            return _lean_replay(policy, ctx, backend)
-        return path_cost
 
     with obs_spans.span(spans, "tournament/hindsight", phase="execute"):
         hs = jax.jit(jax.vmap(
@@ -268,11 +221,16 @@ def run_tournament(
     cost = np.empty((len(resolved), num_f, num_seeds), np.float64)
     for i, policy in enumerate(resolved):
         # One compiled program per policy: the vmap batches every
-        # family's every seed through the same replay.
+        # family's every seed through the same replay.  Each path sets the
+        # policy up over its own P rows (the randomized hedge draws its
+        # thresholds per path), so paths stay the vmap axis rather than
+        # joining the row axis.
         with obs_spans.span(
             spans, f"tournament/{policy.name}", phase="execute"
         ):
-            totals = jax.jit(jax.vmap(make_path_cost(policy)))(flat)
+            totals = jax.jit(jax.vmap(functools.partial(replay, policy)))(
+                flat
+            )
             cost[i] = np.asarray(totals, np.float64).reshape(
                 num_f, num_seeds
             )

@@ -4,17 +4,20 @@ bit-identical to the pre-policy replay (hardcoded golden outputs with
 the Ambati et al. hedging rules honor their per-band ski-rental
 mechanics and classical competitive-ratio bounds on steady fleets, the
 rolling planner beats both hedges on the declining fleet by a pinned
-margin, and the tournament rig's scan replay agrees with its Python-loop
-oracle."""
+margin, the tournament rig's scan replay agrees with its Python-loop
+oracle, and the tournament's cost and hindsight on a path are the
+planner's own on that path."""
 
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.capacity import pricing
+from repro.core import forecast as fc
 from repro.core import planner as pl
 from repro.core import policy as pol
 from repro.core import portfolio as pf
+from repro.core import replan as rp
 from repro.core import tournament as tn
 from repro.core.demand import HOURS_PER_WEEK
 from repro.data import scenarios as sc
@@ -165,17 +168,21 @@ class TestDegeneratePolicies:
         assert float(rep.increments.sum()) > 0  # it does commit
 
 
+def _context(demand, clouds, *, start_weeks):
+    """The context the planner sets a policy up against on a band-free
+    weekly replay of ``demand``."""
+    spec, arrays = rp._replay_inputs(
+        jnp.asarray(demand, jnp.float32), pf.options_from_pricing(), clouds,
+        od=pricing.on_demand_premium(), term_weighting=0.0,
+        cfg=fc.ForecastConfig(), start_weeks=start_weeks, horizon_weeks=2,
+        cadence_weeks=1, policy=pol.get_policy(None),
+    )
+    return rp._policy_context(spec, arrays, fc.solve_prefix)
+
+
 def _hedge_ctx(demand, *, grid_size=3, start_weeks=4, clouds=None):
     clouds = clouds if clouds is not None else ("aws",) * demand.shape[0]
-    return pol.make_context(
-        jnp.asarray(demand, jnp.float32),
-        pf.options_from_pricing(),
-        clouds=clouds,
-        od_rate=pricing.on_demand_premium(),
-        start_weeks=start_weeks,
-        cadence_weeks=1,
-        horizon_weeks=2,
-    )
+    return _context(demand, clouds, start_weeks=start_weeks)
 
 
 def _run_hedge(policy, ctx):
@@ -388,6 +395,46 @@ class TestTournament:
         assert np.isfinite(rep.cost).all()
 
 
+class TestTournamentPlannerParity:
+    """The tournament scores the planner's own replay: on one path, each
+    default policy's tournament cost is the planner's total on that
+    path's PoolSet, and the tournament's hindsight is the planner's."""
+
+    RTOL = 1e-6
+    REPLAY = dict(start_weeks=8, cadence_weeks=2, horizon_weeks=4)
+
+    @pytest.fixture(scope="class")
+    def tournament(self):
+        return tn.run_tournament(
+            families=("burst",), num_pools=2, num_weeks=16, num_seeds=1,
+            **self.REPLAY,
+        )
+
+    @pytest.fixture(scope="class")
+    def pools(self):
+        return sc.scenario_pool_set("burst", num_pools=2, num_weeks=16)
+
+    @pytest.mark.parametrize("policy", tn.DEFAULT_POLICIES)
+    def test_cost_matches_planner(self, tournament, pools, policy):
+        rep = rp.replan_fleet_pools(
+            pools, compare=False, policy=policy, **self.REPLAY
+        )
+        i = tournament.policies.index(policy)
+        np.testing.assert_allclose(
+            tournament.cost[i, 0, 0], rep.total_cost, rtol=self.RTOL
+        )
+
+    @pytest.mark.parametrize("policy", tn.DEFAULT_POLICIES)
+    def test_hindsight_matches_planner(self, tournament, pools, policy):
+        rep = rp.replan_fleet_pools(
+            pools, compare=True, policy=policy, **self.REPLAY
+        )
+        np.testing.assert_allclose(
+            tournament.hindsight_cost[0, 0], rep.hindsight_cost,
+            rtol=self.RTOL,
+        )
+
+
 class TestTournamentAcceptance:
     """The PR's headline numbers: classical hedging bounds hold on the
     steady family, and the paper's forecasting planner beats both
@@ -430,11 +477,9 @@ class TestPolicyProperties:
         demand = sc.scenario_path(
             family, num_pools=2, num_weeks=12, seed=seed
         )
-        return pol.make_context(
-            demand, pf.options_from_pricing(),
-            clouds=tuple(c for c, _, _ in sc.scenario_keys(2)),
-            od_rate=pricing.on_demand_premium(),
-            start_weeks=6, cadence_weeks=1, horizon_weeks=2,
+        return _context(
+            demand, tuple(c for c, _, _ in sc.scenario_keys(2)),
+            start_weeks=6,
         )
 
     def test_hedge_cost_at_least_hindsight_property(self):
@@ -448,8 +493,12 @@ class TestPolicyProperties:
         )
         def run(family, seed):
             ctx = self._ctx(family, seed)
-            cost = float(tn._lean_replay(
-                pol.DeterministicHedgePolicy(grid_size=8), ctx, "scan"
+            cost = float(tn._path_cost(
+                pol.DeterministicHedgePolicy(grid_size=8), ctx.demand,
+                options=ctx.options, clouds=ctx.clouds, od=ctx.od,
+                cfg=fc.ForecastConfig(), start_weeks=ctx.start_weeks,
+                cadence_weeks=ctx.cadence_weeks,
+                horizon_weeks=ctx.horizon_weeks, backend="scan",
             ))
             hind = float(tn._hindsight_cost(
                 ctx.demand, options=ctx.options, clouds=ctx.clouds,

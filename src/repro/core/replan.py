@@ -932,6 +932,32 @@ def _replay_scan(stage: str, spec: _ReplaySpec, arrays: _ReplayArrays,
     return ys
 
 
+@functools.partial(jax.jit, static_argnames=("start", "num_blocks"))
+def _demand_block_sums(demand, start: int, num_blocks: int):
+    """(num_blocks,) sums of ``demand``'s hours from ``start`` on, one per
+    block of consecutive rows (a scenario's pools).  Each row is summed
+    where it lives and each block from its rows' sums, so a row-sharded
+    ``demand`` is not gathered and no slice of the evaluated hours is
+    kept."""
+    return demand[:, start:].sum(axis=1).reshape(num_blocks, -1).sum(axis=1)
+
+
+def _block_totals(bills, num_blocks: int) -> np.ndarray:
+    """(num_blocks,) totals of float32 per-week ``(S, num_blocks * rows,
+    ...)`` bills, one per block of consecutive rows (a scenario's pools or
+    clouds).  Each block is laid out contiguously and summed on its own in
+    float64, so its total does not depend on how many blocks sit beside
+    it; the total is then rounded to float32, the precision of the bills,
+    so bills that agree to their own precision give one total."""
+    out = np.zeros(num_blocks)
+    for a in bills:
+        blocks = np.swapaxes(a.reshape(a.shape[0], num_blocks, -1), 0, 1)
+        out += np.ascontiguousarray(blocks, np.float64).reshape(
+            num_blocks, -1
+        ).sum(axis=1)
+    return out.astype(np.float32).astype(np.float64)
+
+
 def _validate(total_weeks: int, start_weeks: int, cadence_weeks: int):
     if cadence_weeks < 1:
         raise ValueError(f"cadence_weeks must be >= 1, got {cadence_weeks}")
@@ -1392,48 +1418,31 @@ def replan_fleet_pools(
                         ),
                         conv_clouds,
                     )
-            with obs_spans.stage("replan/post/totals"):
-                total = float(ys["committed"].sum() + ys["od"].sum())
-                if sp_res is not None:
-                    total += float(ys["spot"].sum())
-                if conv_opts is not None:
-                    total += float(ys["conv_committed"].sum())
-                eval_demand = demand[:, start_weeks * HOURS_PER_WEEK:]
-                all_od = od * float(eval_demand.sum())
-                scen_cost = None
-                if scen is not None:
-                    # Per-scenario replay cost, sliced row-block by
-                    # row-block in the same summation order as the
-                    # single-path totals — so the N=1 realized batch
-                    # reproduces them bit for bit — and the scalar
-                    # aggregates become means over scenarios.
-                    def _srows(a, s, rows=num_pools):
-                        return a[:, s * rows:(s + 1) * rows]
 
-                    def _scen_total(s):
-                        cs = float(
-                            _srows(ys["committed"], s).sum()
-                            + _srows(ys["od"], s).sum()
-                        )
-                        if sp_res is not None:
-                            cs += float(_srows(ys["spot"], s).sum())
-                        if conv_opts is not None:
-                            cs += float(_srows(
-                                ys["conv_committed"], s, num_clouds
-                            ).sum())
-                        return cs
+            def bills(out):
+                """A replay's per-week bills, in the order they are summed."""
+                keys = ["committed", "od"]
+                keys += ["spot"] if sp_res is not None else []
+                keys += ["conv_committed"] if conv_opts is not None else []
+                return [np.asarray(out[k_]) for k_ in keys]
 
-                    scen_cost = np.asarray(
-                        [_scen_total(s) for s in range(num_scen)]
-                    )
-                    scen_all_od = np.asarray([
-                        od * float(eval_demand[
-                            s * num_pools:(s + 1) * num_pools
-                        ].sum())
-                        for s in range(num_scen)
-                    ])
-                    total = float(scen_cost.mean())
-                    all_od = float(scen_all_od.mean())
+            # Per-scenario totals, the single path being the one block of
+            # N = 1.  The replay's bills are summed on the host block by
+            # block (``_block_totals``), so a block's total does not depend
+            # on the blocks beside it: the N = 1 batch reproduces the single
+            # path, and chunks the whole batch, bit for bit.  The
+            # all-on-demand bill's hours are reduced on the device, one sum
+            # per block, and pulled once.  The scalar aggregates are means
+            # over the blocks.
+            with obs_spans.stage("replan/post/totals", totals_pulls=1):
+                scen_all_od = od * np.asarray(_demand_block_sums(
+                    demand, start_weeks * HOURS_PER_WEEK, num_scen
+                ), np.float64)
+                scen_cost = _block_totals(bills(ys), num_scen)
+                total = float(scen_cost.mean())
+                all_od = float(scen_all_od.mean())
+                if scen is None:
+                    scen_cost = None
             with obs_spans.stage("replan/post/report"):
                 def _rep(a, rows=num_pools):
                     """Report view of a per-week (S, R, ...) array: insert the
@@ -1584,6 +1593,7 @@ def replan_fleet_pools(
             if not compare:
                 return report
             # The hindsight baseline pulls the evaluated demand whole.
+            eval_demand = demand[:, start_weeks * HOURS_PER_WEEK:]
             with obs_spans.stage(
                 "replan/post/baselines", d2h_bytes=int(eval_demand.nbytes)
             ):
@@ -1593,35 +1603,24 @@ def replan_fleet_pools(
                 # purchasing options exist).  Always driven by the standard
                 # rolling policy so a custom ``policy=`` is still scored
                 # against the paper's reference points.
-                one = _replay_scan(
+                one = bills(_replay_scan(
                     "replan/post/baselines/lower",
                     dataclasses.replace(
                         spec, policy=pol.RollingPortfolioPolicy(),
                         cadence_weeks=0, mode="weekly",
                     ),
                     arrays, zeros(), jnp.arange(start_weeks, total_weeks),
-                )
-                one_weekly = _rep(
-                    np.asarray(one["committed"] + one["od"])
-                ).sum(-1)
+                ))
+                one_weekly = _rep(one[0] + one[1]).sum(-1)
                 if sp_res is not None:
-                    one_weekly = one_weekly + _rep(
-                        np.asarray(one["spot"])
-                    ).sum(-1)
+                    one_weekly = one_weekly + _rep(one[2]).sum(-1)
                 if conv_opts is not None:
-                    one_weekly = one_weekly + _rep(
-                        np.asarray(one["conv_committed"]), num_clouds
-                    ).sum(-1)
+                    one_weekly = one_weekly + _rep(one[-1], num_clouds).sum(-1)
                 report.one_shot_weekly_cost = one_weekly
+                scen_one = _block_totals(one, num_scen)
+                report.one_shot_cost = float(scen_one.mean())
                 if scen is not None:
-                    scen_one = (
-                        one_weekly.sum(0) if scen_axis
-                        else np.asarray([one_weekly.sum()])
-                    )
                     report.scenario_one_shot_cost = scen_one
-                    report.one_shot_cost = float(scen_one.mean())
-                else:
-                    report.one_shot_cost = float(one_weekly.sum())
                 report.savings_vs_one_shot = (
                     1.0 - total / report.one_shot_cost
                     if report.one_shot_cost > 0 else 0.0
@@ -1646,16 +1645,14 @@ def replan_fleet_pools(
                 hs_weekly = hs_committed[:, None] + od * hs_over      # (R, S)
                 report.hindsight_widths = hs_widths
                 report.hindsight_weekly_cost = hs_weekly.sum(0)
-                report.hindsight_cost = float(hs_weekly.sum())
+                # Billed as the replay bills: committed, then on-demand.
+                scen_hind = _block_totals([
+                    np.broadcast_to(hs_committed, hs_over.T.shape),
+                    (od * hs_over).T,
+                ], num_scen)
+                report.hindsight_cost = float(scen_hind.mean())
                 if scen is not None:
-                    scen_hind = np.asarray([
-                        float(hs_weekly[
-                            s * num_pools:(s + 1) * num_pools
-                        ].sum())
-                        for s in range(num_scen)
-                    ])
                     report.scenario_hindsight_cost = scen_hind
-                    report.hindsight_cost = float(scen_hind.mean())
                     report.scenario_cr = scen_cost / scen_hind
                     report.scenario_regret = scen_cost - scen_hind
                     if scen_axis:

@@ -48,6 +48,11 @@ GOLDEN_ROLLING = dict(cadence_weeks=2, start_weeks=6, horizon_weeks=4)
 LOOP_RTOL = 2e-4
 GOLDEN_WEEKS = np.arange(6, 20)
 GOLDEN_DECISIONS = np.arange(14) % 2 == 0
+# The per-scenario totals against a float64 re-bill: the replay's float32
+# bills are summed in float64 and each total rounded to float32 (half an
+# ulp, 6e-8); the evaluated hours are summed on the device in float32.
+TOTALS_RTOL = 1e-7
+HOURS_RTOL = 1e-6
 
 
 @pytest.fixture(scope="module")
@@ -263,6 +268,50 @@ class TestScenarioBatchedReplay:
         assert np.array_equal(full.scenario_cr, chunked.scenario_cr)
         assert full.total_cost == chunked.total_cost
         assert chunked.n_scenarios == 4
+
+    @pytest.mark.parametrize(
+        "bands", [{}, {"spot": True, "convertible": True}],
+        ids=["plain", "spot_convertible"],
+    )
+    @pytest.mark.parametrize("n", [1, 4])
+    def test_totals_match_a_float64_rebill(self, pools, n, bands):
+        """Each scenario's total is its own bills re-billed in float64 (the
+        report's committed, on-demand, spot and convertible arrays), and
+        its all-on-demand bill is its own evaluated hours (the replay's
+        rows) at the on-demand rate, block by block."""
+        import jax.numpy as jnp
+
+        from repro.capacity import pricing
+
+        cfg = sc.ScenarioConfig(n_scenarios=n, family="growth")
+        rep = rp.replan_fleet_pools(
+            pools, scenarios=cfg, compare=False, **bands, **GOLDEN_ROLLING
+        )
+
+        def per_scenario(a):
+            a = np.asarray(a, np.float64)
+            return a.reshape(a.shape[0], n, -1).sum(axis=(0, 2))
+
+        bills = [rep.committed_cost, rep.on_demand_cost]
+        if bands:
+            bills += [rep.spot_cost, rep.conv_committed_cost]
+        want = sum(per_scenario(a) for a in bills)
+        np.testing.assert_allclose(rep.scenario_cost, want, rtol=TOTALS_RTOL)
+        np.testing.assert_allclose(
+            rep.total_cost, want.mean(), rtol=TOTALS_RTOL
+        )
+
+        start = GOLDEN_ROLLING["start_weeks"] * 168
+        rows = np.asarray(sc.scenario_batch(pools.demand, cfg))   # (N, P, T)
+        hours = rows[:, :, start:].astype(np.float64).reshape(n, -1).sum(1)
+        got = rp._demand_block_sums(
+            jnp.asarray(rows.reshape(-1, rows.shape[-1])), start, n
+        )
+        np.testing.assert_allclose(np.asarray(got), hours, rtol=HOURS_RTOL)
+        np.testing.assert_allclose(
+            rep.all_on_demand_cost,
+            pricing.on_demand_premium() * hours.mean(), rtol=HOURS_RTOL,
+        )
 
     def test_batched_scan_matches_loop_oracle(self, pools):
         cfg = sc.ScenarioConfig(n_scenarios=3, family="regime")

@@ -594,6 +594,7 @@ class TestStages:
                 "prefix_bytes": self._prefix_bytes(pools)},
             "replan/scan/lower": {"scan_lowerings": 0},
             "replan/pull": {"d2h_bytes": self._pulled_bytes(rep)},
+            "replan/post/totals": {"totals_pulls": 1},
             "replan/post/baselines/lower": {"scan_lowerings": 0},
             "replan/post/baselines": {
                 "d2h_bytes": self.N * pools.num_pools
@@ -602,6 +603,34 @@ class TestStages:
         assert rec.spans[0].parent == -1
         assert all(sp.parent >= 0 for sp in rec.spans[1:])
         assert rec.summary()["replan/pull"]["counts"] == counts["replan/pull"]
+
+    @pytest.mark.parametrize("n", [2, 4])
+    def test_totals_wait_for_one_device_result(self, pools, n,
+                                               monkeypatch):
+        """The per-scenario totals reduce the evaluated demand of every
+        scenario in one device call and wait for its one result, however
+        many scenarios the replay carries: ``totals_pulls`` reads 1."""
+        from repro.obs import recording
+
+        real, calls = replan._demand_block_sums, []
+
+        def spy(demand, start, num_blocks):
+            out = real(demand, start, num_blocks)
+            calls.append(out.shape)
+            return out
+
+        monkeypatch.setattr(replan, "_demand_block_sums", spy)
+        rec = SpanRecorder()
+        with recording(rec):
+            replan.replan_fleet_pools(
+                pools, cadence_weeks=2, start_weeks=self.START,
+                horizon_weeks=4, compare=False,
+                scenarios=sc.ScenarioConfig(n_scenarios=n, family="growth"),
+            )
+        (totals,) = [sp for sp in rec.spans
+                     if sp.name == "replan/post/totals"]
+        assert totals.counts == {"totals_pulls": 1}
+        assert calls == [(n,)]
 
     @pytest.mark.parametrize("family", ["burst", "regime"])
     def test_place_rows_counts_realized_rows_and_draws(self, pools, family):
